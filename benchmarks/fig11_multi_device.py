@@ -90,6 +90,7 @@ print(json.dumps({"us": float(np.median(ts)) * 1e6,
 
 def _point(n_dev: int) -> dict:
     env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH", ""),
                FIG11_NDEV=str(n_dev),
                FIG11_NNZ=str(NNZ_PER_DEV * n_dev),
@@ -180,6 +181,16 @@ def run_stream() -> None:
 
 
 def run() -> None:
+    import jax
+
+    # The weak-scale points run on fake CPU devices in child processes and
+    # the stream points run here; on an accelerator this process would
+    # hold the device while its children start, so refuse up front.
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "fig11 runs fake-CPU-device children next to this process; "
+            f"the {jax.default_backend()} backend serves one process at a "
+            "time. Rerun with JAX_PLATFORMS=cpu.")
     rows = []
     for n_dev in DEVICES:
         rec = _point(n_dev)

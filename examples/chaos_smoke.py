@@ -318,6 +318,19 @@ def main_mesh(out_dir: str) -> None:
           f"{out_dir}/dist_chaos_report.json")
 
 
+def _require_cpu_backend() -> None:
+    """The scenarios run JAX here and then start child processes that run
+    JAX too. An accelerator serves one process at a time, so a parent
+    holding one would block its children: refuse anything but the CPU."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        sys.exit(f"chaos_smoke runs JAX in this process and in child "
+                 f"processes; the {backend} backend serves one process at "
+                 "a time. Rerun with JAX_PLATFORMS=cpu.")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--child", nargs=2, metavar=("CKPT", "OUT"),
@@ -336,6 +349,7 @@ def main():
         child_run_mesh(args.child_mesh[0], args.child_mesh[1],
                        int(args.child_mesh[2]), args.resume)
         return
+    _require_cpu_backend()
     if args.mesh:
         os.makedirs(args.out, exist_ok=True)
         main_mesh(args.out)

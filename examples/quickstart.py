@@ -26,7 +26,9 @@ def main():
     #    one engine state, one jitted lax.scan over the mode rotation.
     rank = 32
     factors = init_factors(jax.random.PRNGKey(0), tensor.dims, rank)
-    config = ExecutionConfig()            # backend="pallas" on TPU
+    # backend="xla" by default; ExecutionConfig(backend="pallas_fused")
+    # runs the Mosaic kernels on a TPU (interpret mode elsewhere)
+    config = ExecutionConfig()
     state = engine.init(tensor, config)
     outs, state = engine.all_modes(state, tuple(factors))
     ref = mttkrp_ref(tensor.indices, tensor.values, factors, 0,

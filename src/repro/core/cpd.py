@@ -24,6 +24,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import engine
 from repro.engine import ExecutionConfig
@@ -45,8 +46,12 @@ def init_factors(key, dims: Sequence[int], rank: int) -> list[jax.Array]:
             zip(keys, dims)]
 
 
+# TPU matmuls default to one bf16 pass; the ALS algebra is f32.
+_HIGHEST = lax.Precision.HIGHEST
+
+
 def gram(f: jax.Array) -> jax.Array:
-    return f.T @ f
+    return jnp.dot(f.T, f, precision=_HIGHEST)
 
 
 @jax.jit
@@ -60,7 +65,8 @@ def _als_update(mttkrp_out, grams_other, eps=1e-8):
     r = v.shape[0]
     ridge = eps + 1e-6 * jnp.trace(v) / r
     v = v + ridge * jnp.eye(r, dtype=v.dtype)
-    y = jnp.linalg.solve(v.T, mttkrp_out.T).T
+    with jax.default_matmul_precision("highest"):   # the solve's matmuls
+        y = jnp.linalg.solve(v.T, mttkrp_out.T).T
     lam = jnp.linalg.norm(y, axis=0)
     lam = jnp.where(lam < eps, 1.0, lam)
     return y / lam, lam
@@ -347,7 +353,8 @@ def _fit(norm_x_sq: float, m_last, factors, lam) -> float:
     g = gram(factors[0])
     for f in factors[1:]:
         g = g * gram(f)
-    norm_est_sq = lam @ g @ lam
+    norm_est_sq = jnp.dot(lam, jnp.dot(g, lam, precision=_HIGHEST),
+                          precision=_HIGHEST)
     resid_sq = jnp.maximum(norm_x_sq - 2 * inner + norm_est_sq, 0.0)
     return float(1.0 - jnp.sqrt(resid_sq) / np.sqrt(norm_x_sq))
 

@@ -197,7 +197,9 @@ def plan_mode(
       indices_d: (nnz,) mode-d index of every nonzero.
       dim: I_d.
       mode: d (bookkeeping only).
-      kappa: partition count; default sized so row tiles fit VMEM.
+      kappa: partition count; default sized so row tiles fit VMEM. With
+        more partitions than rows (a short mode sharded over more
+        devices) the surplus partitions own no rows.
       rows_pp: rows per partition; derived from kappa when not given.
       schedule: ``"compact"`` emits only real blocks plus the block->
         partition descriptor; ``"rect"`` pads every partition to the max
@@ -213,7 +215,6 @@ def plan_mode(
     indices_d = np.ascontiguousarray(indices_d)
     if kappa is None:
         kappa = choose_kappa(dim, rows_pp or DEFAULT_ROWS_PER_PARTITION)
-    kappa = min(kappa, dim)  # never more partitions than rows
     rows_pp = math.ceil(dim / kappa)
 
     # --- Alg. 1 step 1: vertices sorted by degree (descending, stable). ---
@@ -386,7 +387,6 @@ def plan_mode_reference(
     nnz = indices_d.shape[0]
     if kappa is None:
         kappa = choose_kappa(dim, rows_pp or DEFAULT_ROWS_PER_PARTITION)
-    kappa = min(kappa, dim)  # never more partitions than rows
     rows_pp = math.ceil(dim / kappa)
 
     degrees = np.bincount(indices_d, minlength=dim)

@@ -173,8 +173,8 @@ from .state import (EngineState, ModeSched, ModeStatic,
                     mode_static_from_plan)
 from .backends import (BACKENDS, register_backend, get_backend,
                        compute_lrow)
-from .api import (init, mttkrp, all_modes, scan_jaxpr, reset_counters,
-                  TRACE_COUNTS, DISPATCH_COUNTS, FoldFn)
+from .api import (init, mttkrp, all_modes, scan_jaxpr, scan_hlo,
+                  reset_counters, TRACE_COUNTS, DISPATCH_COUNTS, FoldFn)
 from . import dist
 from .dist import (DistConfig, DistState, ExchangeSchedule, shard_state,
                    dist_mttkrp, dist_all_modes, surviving_mesh)
@@ -190,7 +190,7 @@ __all__ = [
     "derive_vmem_budget",
     "platform_default_interpret", "EngineState", "ModeSched", "ModeStatic",
     "mode_static_from_plan", "BACKENDS", "register_backend", "get_backend",
-    "compute_lrow", "init", "mttkrp", "all_modes", "scan_jaxpr",
+    "compute_lrow", "init", "mttkrp", "all_modes", "scan_jaxpr", "scan_hlo",
     "reset_counters", "TRACE_COUNTS", "DISPATCH_COUNTS", "FoldFn",
     "dist", "DistConfig", "DistState", "ExchangeSchedule", "shard_state",
     "dist_mttkrp", "dist_all_modes", "surviving_mesh",

@@ -32,7 +32,8 @@ from repro.obs.metrics import REGISTRY
 from repro.obs.trace import span
 from repro.resilience import chaos as _chaos
 
-from .backends import compute_lrow, get_backend
+from .backends import (compute_lrow, empty_slots, get_backend, pack_slots,
+                       scatter_slots, unpack_slots)
 from .config import ExecutionConfig
 from .state import (EngineState, ModeSched, ModeStatic,
                     mode_static_from_plan)
@@ -197,12 +198,8 @@ def _mode_branch(d: int, *, statics: Sequence[ModeStatic], smax: int,
             # Alg. 3: conflict-free scatter into the mode-(d+1) layout (pads
             # parked at S_max -> dropped); slots beyond S_{d+1} stay empty.
             dst = jnp.where(alive, al[:, nxt], smax)
-            nval = jnp.zeros((smax,), val.dtype).at[dst].set(
-                v, mode="drop", unique_indices=True)
-            nidx = jnp.zeros((smax, n), idx.dtype).at[dst].set(
-                ix, mode="drop", unique_indices=True)
-            nalpha = jnp.full((smax, n), -1, jnp.int32).at[dst].set(
-                al, mode="drop", unique_indices=True)
+            nval, nidx, nalpha = unpack_slots(scatter_slots(
+                dst, pack_slots(v, ix, al), empty_slots(smax, n)))
         out = jnp.take(out_rel, relabels[d], axis=0)  # un-relabel -> (I_d, R)
         if fold is not None:
             factors, carry = fold(d, out, factors, carry)
@@ -295,6 +292,17 @@ def _build_scan(state: EngineState, fold: FoldFn | None):
     return run
 
 
+def _scan_fn(state: EngineState, fold: FoldFn | None):
+    """The jitted all-modes program for ``state``'s static aux (cached)."""
+    key = ("all_modes", state.aux_key(), fold)
+    fn = _JIT_CACHE.get(key)
+    if fn is None:
+        donate = (0,) if state.config.resolve_donate() else ()
+        fn = _JIT_CACHE[key] = jax.jit(_build_scan(state, fold),
+                                       donate_argnums=donate)
+    return fn
+
+
 def all_modes(state: EngineState, factors: Sequence[jax.Array], *,
               fold: FoldFn | None = None, carry=None):
     """spMTTKRP along all N modes as ONE jitted ``lax.scan`` dispatch.
@@ -310,12 +318,7 @@ def all_modes(state: EngineState, factors: Sequence[jax.Array], *,
     the scan right after each mode's output, which is how an ALS sweep
     stays a single traced program.
     """
-    key = ("all_modes", state.aux_key(), fold)
-    fn = _JIT_CACHE.get(key)
-    if fn is None:
-        donate = (0,) if state.config.resolve_donate() else ()
-        fn = _JIT_CACHE[key] = jax.jit(_build_scan(state, fold),
-                                       donate_argnums=donate)
+    fn = _scan_fn(state, fold)
     _c = _chaos.active()
     if _c is not None:
         _c.on_dispatch(state.config.backend)
@@ -339,5 +342,17 @@ def scan_jaxpr(state: EngineState, factors: Sequence[jax.Array],
         tuple(factors), carry)
 
 
-__all__ = ["init", "mttkrp", "all_modes", "scan_jaxpr", "reset_counters",
+def scan_hlo(state: EngineState, factors: Sequence[jax.Array],
+             fold: FoldFn | None = None, carry=None) -> str:
+    """Optimized HLO of the all-modes program ``all_modes`` runs, as XLA
+    compiled it for the state's backend (Pallas kernels compiled through
+    Mosaic appear as ``tpu_custom_call``). Same jitted function, so a
+    persistent compilation cache serves the compile."""
+    return _scan_fn(state, fold).lower(
+        (state.val, state.idx, state.alpha), state.relabel, state.sched,
+        tuple(factors), carry).compile().as_text()
+
+
+__all__ = ["init", "mttkrp", "all_modes", "scan_jaxpr", "scan_hlo",
+           "reset_counters",
            "TRACE_COUNTS", "DISPATCH_COUNTS", "FoldFn"]

@@ -27,7 +27,7 @@ A backend may additionally expose a ``fused_remap`` attribute,
 
 which performs EC *and* the Alg. 3 remap scatter in one kernel pass; the
 engine's scan step delegates to it (unless ``config.fuse_remap`` is off)
-instead of issuing three separate full-``S_max`` XLA scatters.
+instead of issuing the XLA scatter of the slot records.
 
 Registered backends:
   ============  =========================================================
@@ -35,12 +35,12 @@ Registered backends:
                 segment ids come from the block->partition descriptor
                 under the compact schedule, a fixed stride under rect
   pallas        one-hot-MXU Pallas kernel fed by an XLA-materialized
-                ``(S, N-1, R)`` HBM gather — the fusion comparison
+                ``(N-1, R, S)`` HBM gather — the fusion comparison
                 baseline (interpret off-TPU). Compact schedule: the 1-D
                 descriptor-driven grid (``mttkrp_fused_compact``)
   pallas_fused  zero-HBM-intermediate Pallas pipeline: factor rows are
-                gathered *inside* the kernel grid (scalar-prefetched
-                indices + double-buffered ANY->VMEM row DMA) and the
+                gathered *inside* the kernel grid (per-block row lists
+                DMA'd into SMEM + double-buffered ANY->VMEM row DMA) and the
                 Alg. 3 remap scatter is emitted by the same pass via
                 ``fused_remap``. Compact schedule: the gather is
                 *dedup-aware* — each block DMAs only its ``U <= P``
@@ -62,6 +62,7 @@ from typing import Callable, Protocol
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .config import ExecutionConfig
 from .state import ModeStatic
@@ -73,6 +74,12 @@ class ECBackend(Protocol):
 
 
 BACKENDS: dict[str, ECBackend] = {}
+
+#: Slots per step of the ``xla`` backend's gather-multiply-reduce loop. It
+#: bounds the live ``(chunk, R)`` partials, which TPU pads to 128 lanes:
+#: 512 MiB per step, where all ~22M slots of a published-size tensor at
+#: once would not fit one chip's HBM.
+XLA_CHUNK_SLOTS = 1 << 20
 
 
 def register_backend(name: str) -> Callable[[ECBackend], ECBackend]:
@@ -104,6 +111,36 @@ def compute_lrow(idx_d, row_relabel_d, rows_pp: int, alive):
     """Local row ids in the owning partition (relabel table lookup)."""
     rel = jnp.take(row_relabel_d, idx_d, axis=0, mode="fill", fill_value=0)
     return jnp.where(alive, rel % rows_pp, -1)
+
+
+def pack_slots(val, idx, alpha):
+    """The Alg. 3 slot record: ``(S, 2N+1)`` int32 holding ``idx``,
+    ``alpha`` and the bits of the f32 ``val``, so a remap moves a slot with
+    one scatter. (A one-value-per-index scatter over ~22M slots also takes
+    XLA:TPU ~25 s to compile; a 2-D record ~2 s.)"""
+    return jnp.concatenate(
+        [idx, alpha, lax.bitcast_convert_type(val, jnp.int32)[:, None]],
+        axis=1)
+
+
+def empty_slots(size: int, n: int):
+    """``size`` pad records: val 0, idx 0, alpha -1."""
+    return jnp.concatenate([jnp.zeros((size, n), jnp.int32),
+                            jnp.full((size, n), -1, jnp.int32),
+                            jnp.zeros((size, 1), jnp.int32)], axis=1)
+
+
+def unpack_slots(rec):
+    """``(val, idx, alpha)`` of a slot record."""
+    n = (rec.shape[1] - 1) // 2
+    return (lax.bitcast_convert_type(rec[:, 2 * n], jnp.float32),
+            rec[:, :n], rec[:, n:2 * n])
+
+
+def scatter_slots(dst, rec, base):
+    """``base.at[dst].set(rec)`` along the slot axis, destinations unique and
+    out-of-range ones dropped: the Alg. 3 move of slot records."""
+    return base.at[dst].set(rec, mode="drop", unique_indices=True)
 
 
 def _gather_partials(layout, factors, mode: int, accum_dtype):
@@ -150,11 +187,29 @@ def _segment_ids(layout, plan: ModeStatic):
 def ec_xla(layout, factors, mode: int, *, plan: ModeStatic,
            config: ExecutionConfig) -> jax.Array:
     """Fused XLA path: gather-multiply feeding segment-sum directly, so the
-    (S, R) partials never round-trip HBM as a named intermediate."""
-    partials = _gather_partials(layout, factors, mode, config.accum_dtype())
+    (S, R) partials never round-trip HBM as a named intermediate. The slots
+    are reduced in chunks of at most ``XLA_CHUNK_SLOTS`` in a loop."""
+    dtype = config.accum_dtype()
     gid = _segment_ids(layout, plan)
-    return jax.ops.segment_sum(partials, gid,
-                               num_segments=plan.relabeled_rows)
+    s = layout["val"].shape[0]
+    c = min(XLA_CHUNK_SLOTS, s)
+
+    def body(i, acc):
+        # the last chunk is shifted back to end at S; slots an earlier
+        # chunk already summed are masked out as pads
+        start = jnp.minimum(i * c, s - c)
+        chunk = {k: lax.dynamic_slice_in_dim(layout[k], start, c)
+                 for k in ("val", "idx", "lrow")}
+        fresh = start + jnp.arange(c) >= i * c
+        chunk["lrow"] = jnp.where(fresh, chunk["lrow"], -1)
+        partials = _gather_partials(chunk, factors, mode, dtype)
+        return acc + jax.ops.segment_sum(
+            partials, lax.dynamic_slice_in_dim(gid, start, c),
+            num_segments=plan.relabeled_rows)
+
+    r = factors[0].shape[1]
+    return lax.fori_loop(0, -(-s // c), body,
+                         jnp.zeros((plan.relabeled_rows, r), dtype))
 
 
 @register_backend("ref")
@@ -177,9 +232,8 @@ def ec_pallas(layout, factors, mode: int, *, plan: ModeStatic,
 
     gathered = jnp.stack(
         [jnp.take(f, layout["idx"][:, w], axis=0, mode="fill",
-                  fill_value=0.0)
-         for w, f in enumerate(factors) if w != mode],
-        axis=1)  # (S, N-1, R)
+                  fill_value=0.0).T
+         for w, f in enumerate(factors) if w != mode])  # (N-1, R, S)
     if plan.schedule == "compact":
         return kops.mttkrp_fused_compact(
             gathered,
@@ -205,7 +259,7 @@ def ec_pallas(layout, factors, mode: int, *, plan: ModeStatic,
 
 
 def _fused_lidx(layout, nmodes: int, mode: int):
-    """(N-1, S) scalar-prefetch table: per slot, the row of each *input*
+    """(N-1, S) row table: per slot, the row of each *input*
     factor to gather (pads hold in-bounds 0 — killed later by the one-hot
     / dst < 0, so the garbage gather is harmless)."""
     idx = layout["idx"]
@@ -217,8 +271,8 @@ def _fused_lidx(layout, nmodes: int, mode: int):
 def ec_pallas_fused(layout, factors, mode: int, *, plan: ModeStatic,
                     config: ExecutionConfig) -> jax.Array:
     """Zero-HBM-intermediate Pallas pipeline: the factor-row gather happens
-    inside the kernel grid (scalar-prefetched indices, double-buffered
-    ANY->VMEM row DMA), so no ``(S, N-1, R)`` intermediate is ever
+    inside the kernel grid (per-block row lists, double-buffered
+    ANY->VMEM row DMA), so no ``(N-1, R, S)`` intermediate is ever
     materialized. Under the compact schedule the gather is dedup-aware:
     each block DMAs only its unique factor rows. This entry is the
     plain-EC contract used under ``shard_map`` too; the single-device scan
@@ -258,9 +312,16 @@ def _pallas_fused_remap(layout, factors, mode: int, *, plan: ModeStatic,
                         config: ExecutionConfig, smax: int, next_mode: int):
     """EC + Alg. 3 remap in ONE Pallas pass (see module docstring). The
     remap destinations are ``alpha[:, next_mode]`` verbatim: alive slots
-    hold their next-layout slot, pads hold -1 and are skipped in-kernel."""
+    hold their next-layout slot, pads hold -1 and are skipped in-kernel.
+    A compiled (non-interpret) kernel keeps the next layout in VMEM, so a
+    plan whose ``S_max`` does not fit raises ``ValueError`` here rather
+    than falling back to the XLA scatter."""
     from repro.kernels import ops as kops
+    from repro.kernels.mttkrp_kernel import check_fused_remap_fits
 
+    if not config.resolve_interpret():
+        check_fused_remap_fits(smax, len(factors), factors[0].shape[1],
+                               plan.rows_pp, plan.block_p)
     inputs = tuple(f for w, f in enumerate(factors) if w != mode)
     if plan.schedule == "compact":
         out_rel, nval, nidx, nalpha = kops.mttkrp_fused_remap_compact(
@@ -307,4 +368,5 @@ ec_pallas_fused.needs_dedup = True
 
 
 __all__ = ["BACKENDS", "register_backend", "get_backend", "compute_lrow",
+           "pack_slots", "empty_slots", "unpack_slots", "scatter_slots",
            "ec_xla", "ec_ref", "ec_pallas", "ec_pallas_fused"]

@@ -215,8 +215,9 @@ class ExecutionConfig:
     def kappa_for(self, dim: int, n_dev: int = 1) -> int:
         """Partition count for a mode of size ``dim`` under this config's
         kappa policy, rounded so each of ``n_dev`` devices owns an equal,
-        contiguous run of partitions (``kappa % n_dev == 0`` and
-        ``kappa <= dim``, so ``plan_mode`` never clamps it).
+        contiguous run of partitions (``kappa % n_dev == 0``, and
+        ``kappa <= dim`` unless the mode has fewer rows than devices: then
+        every device owns one partition and the surplus ones are empty).
 
         This is the single source of the per-device rounding rule — the
         engine, ``core.distributed.build_sharded_flycoo``, and benchmarks
@@ -231,12 +232,8 @@ class ExecutionConfig:
             base = choose_kappa(dim, rows_pp) if rows_pp else choose_kappa(dim)
         if n_dev <= 1:
             return min(base, dim)
-        if dim < n_dev:
-            raise ValueError(
-                f"mode of size {dim} cannot shard over {n_dev} devices "
-                "(fewer rows than devices)")
         kappa = max(n_dev, math.ceil(base / n_dev) * n_dev)
-        return min(kappa, (dim // n_dev) * n_dev)
+        return min(kappa, max(n_dev, (dim // n_dev) * n_dev))
 
 
 __all__ = ["ExecutionConfig", "KAPPA_POLICIES", "SCHEDULES", "RESIDENCIES",

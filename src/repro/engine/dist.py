@@ -63,22 +63,15 @@ from repro.resilience import chaos as _chaos
 from repro.sharding import ShardingCtx
 
 from .api import _JIT_CACHE, DISPATCH_COUNTS, TRACE_COUNTS, FoldFn
-from .backends import compute_lrow, get_backend
+from .backends import (compute_lrow, empty_slots, get_backend, pack_slots,
+                       scatter_slots, unpack_slots)
 from .config import ExecutionConfig
 from .state import EngineState, ModeSched, ModeStatic
 
-try:  # jax >= 0.6 spells it jax.shard_map
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 EXCHANGES = ("permute", "all_gather")
@@ -471,36 +464,24 @@ def _exchange_permute(v, ix, al, alive, *, nxt, hops, smax_loc, n_dev, da,
     dst_dev = dstg // smax_loc              # floor div: dead -> -1
     mine = alive & (dst_dev == me)
     dst = jnp.where(mine, dstg % smax_loc, smax_loc)
-    nval = jnp.zeros((smax_loc,), v.dtype).at[dst].set(
-        v, mode="drop", unique_indices=True)
-    nidx = jnp.zeros((smax_loc, nmodes), ix.dtype).at[dst].set(
-        ix, mode="drop", unique_indices=True)
-    nalpha = jnp.full((smax_loc, nmodes), -1, jnp.int32).at[dst].set(
-        al, mode="drop", unique_indices=True)
+    rec = pack_slots(v, ix, al)
+    nrec = scatter_slots(dst, rec, empty_slots(smax_loc, nmodes))
 
     for h in range(1, n_dev):
         cap = hops[h - 1]
         if cap == 0:    # statically empty hop: no collective at all
             continue
         sel = alive & (dst_dev == (me + h) % n_dev)
-        # pack outgoing elements densely; schedule guarantees fit <= cap
+        # pack outgoing elements densely (distinct slots; the rest are
+        # dropped); schedule guarantees fit <= cap
         bpos = jnp.where(sel, jnp.cumsum(sel) - 1, cap)
-        bval = jnp.zeros((cap,), v.dtype).at[bpos].set(v, mode="drop")
-        bidx = jnp.zeros((cap, nmodes), ix.dtype).at[bpos].set(
-            ix, mode="drop")
-        balpha = jnp.full((cap, nmodes), -1, jnp.int32).at[bpos].set(
-            al, mode="drop")
+        buf = scatter_slots(bpos, rec, empty_slots(cap, nmodes))
         perm = [(k, (k + h) % n_dev) for k in range(n_dev)]
-        rval = lax.ppermute(bval, da, perm)
-        ridx = lax.ppermute(bidx, da, perm)
-        ralpha = lax.ppermute(balpha, da, perm)
-        rdst = ralpha[:, nxt]               # arrivals all target me
+        rbuf = lax.ppermute(buf, da, perm)
+        rdst = rbuf[:, nmodes + nxt]        # arrivals all target me
         rloc = jnp.where(rdst >= 0, rdst % smax_loc, smax_loc)
-        nval = nval.at[rloc].set(rval, mode="drop", unique_indices=True)
-        nidx = nidx.at[rloc].set(ridx, mode="drop", unique_indices=True)
-        nalpha = nalpha.at[rloc].set(ralpha, mode="drop",
-                                     unique_indices=True)
-    return nval, nidx, nalpha
+        nrec = scatter_slots(rloc, rbuf, nrec)
+    return unpack_slots(nrec)
 
 
 def _exchange_all_gather(v, ix, al, alive, *, d, nxt, smax_loc, n_dev, da,
@@ -510,21 +491,13 @@ def _exchange_all_gather(v, ix, al, alive, *, d, nxt, smax_loc, n_dev, da,
     local slice. O(n_dev * nnz) wire traffic per transition."""
     del alive
     total = n_dev * smax_loc
-    vg = lax.all_gather(v, da, tiled=True)
-    ig = lax.all_gather(ix, da, tiled=True)
-    ag = lax.all_gather(al, da, tiled=True)
-    alive_g = ag[:, d] >= 0
-    dst = jnp.where(alive_g, ag[:, nxt], total)
-    nval = jnp.zeros((total,), v.dtype).at[dst].set(
-        vg, mode="drop", unique_indices=True)
-    nidx = jnp.zeros((total, nmodes), ix.dtype).at[dst].set(
-        ig, mode="drop", unique_indices=True)
-    nalpha = jnp.full((total, nmodes), -1, jnp.int32).at[dst].set(
-        ag, mode="drop", unique_indices=True)
+    rec = lax.all_gather(pack_slots(v, ix, al), da, tiled=True)
+    ag = rec[:, nmodes:2 * nmodes]
+    dst = jnp.where(ag[:, d] >= 0, ag[:, nxt], total)
+    nrec = scatter_slots(dst, rec, empty_slots(total, nmodes))
     me = lax.axis_index(da)
-    sl = lambda a: lax.dynamic_slice_in_dim(  # noqa: E731
-        a, me * smax_loc, smax_loc, axis=0)
-    return sl(nval), sl(nidx), sl(nalpha)
+    return unpack_slots(lax.dynamic_slice_in_dim(
+        nrec, me * smax_loc, smax_loc, axis=0))
 
 
 # --------------------------------------------------------------------------
@@ -659,6 +632,18 @@ def _build_dist_step(dstate: DistState):
 # --------------------------------------------------------------------------
 # Public execution API.
 # --------------------------------------------------------------------------
+def _place_operands(dstate: DistState, factors, carry, fold):
+    """Commit factors and carry to the shardings the program hands them
+    back with, so the first sweep (host-initialized factors) and every
+    later one (mesh-resident outputs) share one trace."""
+    _, fac_spec, _ = _specs(dstate, fold)
+    fac_sh = NamedSharding(dstate.mesh, fac_spec)
+    rep = NamedSharding(dstate.mesh, P())
+    factors = tuple(jax.device_put(f, fac_sh) for f in factors)
+    carry = jax.tree.map(lambda x: jax.device_put(x, rep), carry)
+    return factors, carry
+
+
 def _gate_dispatch(dstate: DistState, policy, what: str):
     """Run the chaos hook for one dist dispatch, retrying *transient*
     failures with the same policy-driven backoff stream uploads use.
@@ -699,12 +684,13 @@ def dist_mttkrp(dstate: DistState, factors: Sequence[jax.Array], *,
         fn = _JIT_CACHE[key] = jax.jit(_build_dist_step(dstate),
                                        donate_argnums=donate)
     _gate_dispatch(dstate, policy, "dist_mttkrp")
+    factors, _ = _place_operands(dstate, factors, None, None)
     DISPATCH_COUNTS["dist_mttkrp"] += 1
     with span("engine.dispatch", kind="dist_mttkrp", mode=dstate.mode,
               n_dev=int(dstate.n_dev)):
         (nval, nidx, nalpha), out = fn(
             (dstate.val, dstate.idx, dstate.alpha), dstate.relabel,
-            dstate.sched, tuple(factors), None)
+            dstate.sched, factors, None)
     nxt = (dstate.mode + 1) % dstate.nmodes
     return out, dstate.replace(val=nval, idx=nidx, alpha=nalpha, mode=nxt)
 
@@ -727,12 +713,13 @@ def dist_all_modes(dstate: DistState, factors: Sequence[jax.Array], *,
         fn = _JIT_CACHE[key] = jax.jit(_build_dist_scan(dstate, fold),
                                        donate_argnums=donate)
     _gate_dispatch(dstate, policy, "dist_all_modes")
+    factors, carry = _place_operands(dstate, factors, carry, fold)
     DISPATCH_COUNTS["dist_all_modes"] += 1
     with span("engine.dispatch", kind="dist_all_modes",
               start_mode=dstate.mode, n_dev=int(dstate.n_dev)):
         layout3, outs, out_factors, out_carry = fn(
             (dstate.val, dstate.idx, dstate.alpha), dstate.relabel,
-            dstate.sched, tuple(factors), carry)
+            dstate.sched, factors, carry)
     nval, nidx, nalpha = layout3
     next_state = dstate.replace(val=nval, idx=nidx, alpha=nalpha)
     if fold is None:

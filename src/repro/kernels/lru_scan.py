@@ -17,11 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory spaces; interpret mode works without a real TPU
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)  # noqa: E731
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY  # noqa: E731
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _lru_kernel(a_ref, x_ref, o_ref, h_ref, *, chunk: int):
@@ -56,6 +52,6 @@ def lru_scan(a: jax.Array, x: jax.Array, *, chunk: int = 32,
         ],
         out_specs=pl.BlockSpec((b, chunk, d), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, t, d), jnp.float32),
-        scratch_shapes=[_SCRATCH((b, d))],
+        scratch_shapes=[pltpu.VMEM((b, d), jnp.float32)],
         interpret=interpret,
     )(a.astype(jnp.float32), x.astype(jnp.float32))

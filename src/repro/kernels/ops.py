@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On a real TPU the kernels compile through Mosaic; on this CPU container we
-default to ``interpret=True`` (the kernel body runs as traced JAX ops) so
-correctness is validated end-to-end. Dry-run/roofline lowering uses the
+On a TPU the kernels compile through Mosaic; on any other backend the
+default is ``interpret=True`` (the kernel body runs as traced JAX ops), so
+correctness is validated end-to-end on the CPU too. Dry-run/roofline lowering uses the
 XLA reference paths so ``cost_analysis()`` reports honest HLO (DESIGN.md §6).
 
 Interpret resolution is policy, not plumbing: every wrapper accepts either

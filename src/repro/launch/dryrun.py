@@ -122,8 +122,6 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax < 0.6 wraps the dict
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
     rec = {

@@ -3,24 +3,16 @@
 Defined as functions — importing this module never touches jax device state.
 Single pod: (data=16, model=16) = 256 chips; multi-pod adds a leading
 pod axis: (pod=2, data=16, model=16) = 512 chips.
-
-``AxisType`` landed after jax 0.4; on older installs ``jax.make_mesh`` has
-no ``axis_types`` kwarg and every axis is implicitly Auto, so the gate
-below changes nothing semantically.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:
-    from jax.sharding import AxisType
 
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # jax <= 0.4: no AxisType, no axis_types kwarg
-    def _mesh(shape, axes):
-        return jax.make_mesh(shape, axes)
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -138,27 +138,6 @@ class ModelConfig:
 # --------------------------------------------------------------------------
 # Primitives
 # --------------------------------------------------------------------------
-@jax.custom_jvp
-def opt_barrier(x):
-    """``lax.optimization_barrier`` that is transparent to autodiff.
-
-    The barrier only constrains XLA scheduling (here: pinning a bf16 cast
-    before a gather/all-gather so collectives move bf16, not the f32
-    masters); mathematically it is the identity, so its tangent/cotangent
-    is the identity too. ``lax.optimization_barrier`` itself has no
-    differentiation rule, which made every ``value_and_grad`` over these
-    models raise — the custom JVP scopes the barrier to the primal
-    computation, where it matters.
-    """
-    return jax.lax.optimization_barrier(x)
-
-
-@opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return opt_barrier(x), t
-
-
 def dense_init(key, shape, dtype, scale: Optional[float] = None):
     fan_in = shape[0] if len(shape) >= 2 else 1
     std = scale if scale is not None else 1.0 / math.sqrt(fan_in)

@@ -21,8 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..sharding import shard
-from .common import (ModelConfig, apply_norm, dense_init, init_norm,
-                     opt_barrier)
+from .common import ModelConfig, apply_norm, dense_init, init_norm
 from . import layers, moe, rglru, rwkv
 
 
@@ -271,7 +270,8 @@ def embed_lookup(params, ids, cfg: ModelConfig):
         from ..tensorized import cpd_embed
 
         return cpd_embed(params["embed_cpd"], ids).astype(cfg.cdtype)
-    table = opt_barrier(params["embed"].astype(cfg.cdtype))
+    # pin the bf16 cast before the gather so collectives move bf16
+    table = jax.lax.optimization_barrier(params["embed"].astype(cfg.cdtype))
     return jnp.take(table, ids, axis=0)
 
 

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 from .. import sharding
 from ..models import forward
-from ..models.common import ModelConfig, opt_barrier
+from ..models.common import ModelConfig
 from . import optimizer as opt_lib
 from .optimizer import OptimizerConfig
 
@@ -137,7 +137,7 @@ def make_train_step(cfg: ModelConfig, ocfg: OptimizerConfig,
                 c = p.astype(cfg.cdtype)
                 if s is not None:
                     c = jax.lax.with_sharding_constraint(c, s)
-                return opt_barrier(c)
+                return jax.lax.optimization_barrier(c)
 
             if param_shardings is not None:
                 fwd_params = jax.tree.map(cast, params, param_shardings)

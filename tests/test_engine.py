@@ -62,6 +62,29 @@ def test_all_modes_backend_parity(backend, nmodes):
                                        atol=2e-4)
 
 
+@pytest.mark.parametrize("chunk", [1000, 4096])
+def test_xla_backend_chunked_reduction(monkeypatch, chunk):
+    """Layouts over ``XLA_CHUNK_SLOTS`` are reduced in a loop of chunks
+    (the last one shifted back, its overlap masked): same result as the
+    oracle, including the remapped second rotation."""
+    from repro.engine import backends
+
+    dims = DIMS_BY_NMODES[4]
+    idx, val, t = _tensor(11, dims, 6000, rows_pp=4, block_p=16)
+    factors = tuple(init_factors(jax.random.PRNGKey(2), dims, 8))
+    monkeypatch.setattr(backends, "XLA_CHUNK_SLOTS", chunk)
+    monkeypatch.setattr(engine.api, "_JIT_CACHE", {})
+    state = engine.init(t, ExecutionConfig(backend="xla"))
+    assert state.statics[0].padded_nnz > chunk
+    assert state.statics[0].padded_nnz % chunk     # a shifted last chunk
+    refs = _refs(idx, val, factors, dims)
+    for _ in range(2):
+        outs, state = engine.all_modes(state, factors)
+        for d in range(len(dims)):
+            np.testing.assert_allclose(outs[d], refs[d], rtol=2e-5,
+                                       atol=2e-5)
+
+
 @pytest.mark.parametrize("nmodes", [3, 4, 5, 6])
 def test_pallas_fused_any_start_and_step(nmodes):
     """The fused EC+remap pipeline works from any resident mode, both as
@@ -180,7 +203,8 @@ def test_fused_scan_has_no_gathered_intermediate():
     nm1 = len(dims) - 1
 
     state, fused_txt = _scan_hlo(t, "pallas_fused", factors)
-    gathered_types = [f"tensor<{s.padded_nnz}x{nm1}x{rank}xf32>"
+    # the pre-gathered kernel operand is lane-dense (N-1, R, S)
+    gathered_types = [f"tensor<{nm1}x{rank}x{s.padded_nnz}xf32>"
                       for s in state.statics]
     for ty in gathered_types:
         assert ty not in fused_txt, \
@@ -189,7 +213,7 @@ def test_fused_scan_has_no_gathered_intermediate():
     # ... while the unfused pallas baseline does stage it through HBM.
     _, base_txt = _scan_hlo(t, "pallas", factors)
     assert any(ty in base_txt for ty in gathered_types), \
-        "baseline should show the (S, N-1, R) gathered intermediate"
+        "baseline should show the (N-1, R, S) gathered intermediate"
 
 
 def test_fuse_remap_knob_and_vmem_budget():
@@ -324,7 +348,8 @@ def test_execution_config_static_and_validated():
 
 def test_kappa_for_rounds_to_device_multiples():
     """One kappa policy for single- and multi-device plans: divisible by
-    n_dev, never exceeding the row count, honoring fixed/vmem policies."""
+    n_dev, never exceeding the row count unless the mode has fewer rows
+    than devices, honoring fixed/vmem policies."""
     cfg = ExecutionConfig(rows_pp=8)
     from repro.core.partition import choose_kappa
     assert cfg.kappa_for(40) == choose_kappa(40, 8)
@@ -338,8 +363,9 @@ def test_kappa_for_rounds_to_device_multiples():
     assert fixed.kappa_for(100) == 3
     assert fixed.kappa_for(100, 4) == 4
     assert fixed.kappa_for(100, 2) == 4
-    with pytest.raises(ValueError, match="fewer rows than devices"):
-        ExecutionConfig().kappa_for(3, 4)
+    # fewer rows than devices: one partition per device, surplus empty
+    assert ExecutionConfig().kappa_for(3, 4) == 4
+    assert ExecutionConfig().kappa_for(2, 4) == 4
 
 
 def test_init_from_raw_coo_uses_config_policy():

@@ -20,7 +20,9 @@ def test_mttkrp_fused_shapes(kappa, rows_pp, blocks_pp, p, nm1, r):
     val[lrow < 0] = 0.0
     args = (jnp.asarray(g), jnp.asarray(val), jnp.asarray(lrow))
     kw = dict(kappa=kappa, rows_pp=rows_pp, blocks_pp=blocks_pp, block_p=p)
-    out = ops.mttkrp_fused(*args, **kw, interpret=True)
+    # the kernel takes the lane-dense (N-1, R, S) layout of the operand
+    out = ops.mttkrp_fused(jnp.transpose(args[0], (1, 2, 0)), *args[1:],
+                           **kw, interpret=True)
     exp = ref.mttkrp_fused_ref(*args, **kw)
     np.testing.assert_allclose(out, exp, rtol=1e-4, atol=1e-4)
 
@@ -153,7 +155,7 @@ def test_mttkrp_fused_compact_shapes(kappa, part_blocks, p, nm1, r):
     deliberately unbalanced per-partition block counts."""
     c = _compact_case(kappa * 10 + p, kappa, part_blocks, p, nm1, r)
     out = ops.mttkrp_fused_compact(
-        c["gathered"], c["val"], c["lrow"], c["bpart"], kappa=c["kappa"],
+        jnp.transpose(c["gathered"], (1, 2, 0)), c["val"], c["lrow"], c["bpart"], kappa=c["kappa"],
         rows_pp=c["rows_pp"], nblocks=c["nblocks"], block_p=c["p"],
         interpret=True)
     np.testing.assert_allclose(out, c["exp"], rtol=1e-4, atol=1e-4)
