@@ -177,8 +177,6 @@ def cp_als(
     if key is None:
         key = jax.random.PRNGKey(0)
     n = tensor.nmodes
-    factors = tuple(init_factors(key, tensor.dims, rank))
-    lam = jnp.ones((rank,), jnp.float32)
     mesh_raw = None
     if mesh is not None:
         from repro.sharding import ShardingCtx
@@ -200,13 +198,17 @@ def cp_als(
             st = engine.dist.shard_state(st, mesh, dist)
         return st
 
-    state = build_state(config)
     if mesh is None:
         sweep = engine.all_modes
     else:
         sweep = functools.partial(engine.dist.dist_all_modes,
                                   policy=policy)
-    norm_x_sq = float(np.sum(tensor.values.astype(np.float64) ** 2))
+    # Everything a start does before its first sweep.
+    with span("cpd.start"):
+        factors = tuple(init_factors(key, tensor.dims, rank))
+        lam = jnp.ones((rank,), jnp.float32)
+        state = build_state(config)
+        norm_x_sq = float(np.sum(tensor.values.astype(np.float64) ** 2))
 
     store = as_store(checkpoint)
     fits: list = []
@@ -327,7 +329,8 @@ def cp_als(
                     continue
                 break
             if rewind is None and track_fit:
-                fit = _fit(norm_x_sq, outs[n - 1], factors, lam)
+                with span("cpd.fit"):   # the host's sync on the sweep
+                    fit = _fit(norm_x_sq, outs[n - 1], factors, lam)
                 fits.append(fit)
                 sp.set("fit", float(fit))
                 _obs_gauge("cpd_fit", "latest ALS fit per tier").set(

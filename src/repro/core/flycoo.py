@@ -113,8 +113,9 @@ class FlycooTensor:
     indices: np.ndarray           # (nnz, N) int32, canonical order
     values: np.ndarray            # (nnz,) float32, canonical order
     plans: list[ModePlan]
-    # per-mode dedup tables, built lazily once (engine init + dma_row_model
-    # + the autotuner's exact cost stage all consume the same tables)
+    # per-mode dedup tables and their row-copy sums, built lazily once
+    # (engine init + dma_row_model + the autotuner's exact cost stage all
+    # consume the same tables)
     _dedup_cache: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
 
@@ -174,6 +175,17 @@ class FlycooTensor:
             self._dedup_cache[d] = cached
         return cached
 
+    def dedup_row_copies(self, d: int) -> int:
+        """``nuniq.sum()`` of :meth:`dedup_tables`: the factor-row DMAs one
+        pass of the mode-``d`` fused gather issues. Memoized with the
+        tables."""
+        key = ("row_copies", d)
+        copies = self._dedup_cache.get(key)
+        if copies is None:
+            _, _, nuniq = self.dedup_tables(d)
+            copies = self._dedup_cache[key] = int(nuniq.sum())
+        return copies
+
     def trivial_dedup_tables(self, d: int):
         """Dedup-off tables in the same ``(uidx, upos, nuniq)`` encoding.
 
@@ -201,12 +213,12 @@ class FlycooTensor:
         the dedup stage removes."""
         plan = self.plans[d]
         nm1 = self.nmodes - 1
-        _, _, nuniq = self.dedup_tables(d)
+        rows = self.dedup_row_copies(d)
         per_slot = plan.nblocks * plan.block_p * nm1
         return {
             "per_slot_rows": int(per_slot),
-            "dedup_rows": int(nuniq.sum()),
-            "dedup_reduction_x": float(per_slot / max(int(nuniq.sum()), 1)),
+            "dedup_rows": rows,
+            "dedup_reduction_x": float(per_slot / max(rows, 1)),
         }
 
     # -------------------------------------------------------------- metadata

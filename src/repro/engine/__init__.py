@@ -90,7 +90,10 @@ Observability (``repro.obs``):
   exact / measured), ``engine.dispatch`` per jitted call, streamed
   ``stream.mode``/``stream.upload``/``stream.compute``/``stream.remap``
   per chunk, ``dist.shard_state`` + exchange-schedule build, and
-  ``cpd.sweep`` with per-sweep fit. Tracing is OFF by default and free
+  ``cpd.sweep`` with per-sweep fit. The all-modes program names each
+  mode's step ``mode<d>`` with ``ec``/``remap``/``fold`` scopes, mapped
+  to its compiled instructions by ``api.op_scopes()``, and ``init`` sets
+  the ``engine_row_copies`` gauge. Tracing is OFF by default and free
   when off (a single ``is None`` test per span site); enable with
   ``repro.obs.enable()`` or ``REPRO_TRACE=1`` (``REPRO_TRACE=path.json``
   additionally writes a Perfetto-loadable Chrome trace at exit), then

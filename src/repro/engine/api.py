@@ -21,7 +21,8 @@ traced program (see ``repro.core.cpd``).
 """
 from __future__ import annotations
 
-from typing import Callable, Sequence
+import re
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ import numpy as np
 from jax import lax
 
 from repro.obs.metrics import REGISTRY
-from repro.obs.trace import span
+from repro.obs.trace import get_tracer, span
 from repro.resilience import chaos as _chaos
 
 from .backends import (compute_lrow, empty_slots, get_backend, pack_slots,
@@ -54,8 +55,16 @@ TRACE_COUNTS = REGISTRY.counter(
     "engine_traces", "program (re)builds per entry point")
 DISPATCH_COUNTS = REGISTRY.counter(
     "engine_dispatches", "jitted calls issued per entry point")
+# Factor-row DMAs one pass of the mode-d EC kernel issues: the sum of the
+# dedup tables' per-block unique-row counts, the bound of the kernel's
+# row-copy loop (0 where the backend stages no rows through dedup tables).
+ROW_COPIES = REGISTRY.gauge(
+    "engine_row_copies", "factor-row DMAs per EC kernel pass, per mode")
 
 _JIT_CACHE: dict = {}
+# Argument shapes (jax.ShapeDtypeStruct, with shardings) of each all-modes
+# program's first call, under its _JIT_CACHE key: what op_scopes compiles.
+_SCAN_ARGS: dict = {}
 
 
 def reset_counters() -> None:
@@ -104,7 +113,7 @@ def init(tensor, config: ExecutionConfig | None = None,
         with span("engine.sched_tables"):
             sched = tuple(_mode_sched(tensor, d, config) for d in range(n))
         with span("engine.device_place"):
-            return EngineState(
+            state = EngineState(
                 val=jnp.asarray(val),
                 idx=jnp.asarray(idx),
                 alpha=jnp.asarray(alpha),
@@ -116,6 +125,15 @@ def init(tensor, config: ExecutionConfig | None = None,
                 statics=statics,
                 config=config,
             )
+    tracer = get_tracer()
+    if tracer is not None:
+        # The transfers run behind device_place's return; only while
+        # tracing, wait for them so the span ends with the state on the
+        # device. The first sweep reads these buffers anyway.
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(state))
+        with tracer.span("engine.upload", bytes=nbytes):
+            jax.block_until_ready(state)
+    return state
 
 
 def _mode_sched(tensor, d: int, config: ExecutionConfig) -> ModeSched:
@@ -130,9 +148,14 @@ def _mode_sched(tensor, d: int, config: ExecutionConfig) -> ModeSched:
     bpart = jnp.asarray(plan.block_part)
     if plan.schedule != "compact" or \
             not getattr(get_backend(config), "needs_dedup", False):
+        ROW_COPIES.set(d, 0)
         return ModeSched(bpart=bpart)
-    uidx, upos, nuniq = (tensor.dedup_tables(d) if config.dedup
-                         else tensor.trivial_dedup_tables(d))
+    if config.dedup:
+        uidx, upos, nuniq = tensor.dedup_tables(d)
+        ROW_COPIES.set(d, tensor.dedup_row_copies(d))
+    else:
+        uidx, upos, nuniq = tensor.trivial_dedup_tables(d)
+        ROW_COPIES.set(d, int(nuniq.sum()))
     return ModeSched(bpart=bpart, uidx=jnp.asarray(uidx),
                      upos=jnp.asarray(upos), nuniq=jnp.asarray(nuniq))
 
@@ -178,31 +201,47 @@ def _mode_branch(d: int, *, statics: Sequence[ModeStatic], smax: int,
              if config.fuse_remap else None)
 
     def step(layout3, relabels, sched, factors, carry):
+        with jax.named_scope(f"mode{d}"):
+            return _step(layout3, relabels, sched, factors, carry)
+
+    # The scopes name each op's layer in the compiled program's metadata
+    # (``op_scopes``); the ops keep the order they had without them, so
+    # the optimized HLO differs in metadata only.
+    def _step(layout3, relabels, sched, factors, carry):
         val, idx, alpha = layout3
-        v, ix, al = val[:sd], idx[:sd], alpha[:sd]
-        alive = al[:, d] >= 0
-        lrow = compute_lrow(ix[:, d], relabels[d], plan.rows_pp, alive)
-        layout = {"val": v, "idx": ix, "alpha": al, "lrow": lrow,
-                  **sched[d]._asdict()}
-        if fused is not None:
-            # One Pallas pass: EC + remap; slots beyond S_{d+1} stay empty
-            # (the kernel initializes the next layout to the pad pattern).
-            out_rel, (nval, nidx, nalpha) = fused(
-                layout, tuple(factors), d, plan=plan, config=config,
-                smax=smax, next_mode=nxt)
-            nval = nval.astype(val.dtype)
-            nidx = nidx.astype(idx.dtype)
-        else:
-            out_rel = backend(layout, tuple(factors), d, plan=plan,
-                              config=config)
-            # Alg. 3: conflict-free scatter into the mode-(d+1) layout (pads
-            # parked at S_max -> dropped); slots beyond S_{d+1} stay empty.
-            dst = jnp.where(alive, al[:, nxt], smax)
-            nval, nidx, nalpha = unpack_slots(scatter_slots(
-                dst, pack_slots(v, ix, al), empty_slots(smax, n)))
-        out = jnp.take(out_rel, relabels[d], axis=0)  # un-relabel -> (I_d, R)
+        with jax.named_scope("ec"):
+            v, ix, al = val[:sd], idx[:sd], alpha[:sd]
+            alive = al[:, d] >= 0
+            lrow = compute_lrow(ix[:, d], relabels[d], plan.rows_pp, alive)
+            layout = {"val": v, "idx": ix, "alpha": al, "lrow": lrow,
+                      **sched[d]._asdict()}
+            if fused is not None:
+                # One Pallas pass: EC + remap; slots beyond S_{d+1} stay
+                # empty (the kernel initializes the next layout to the pad
+                # pattern).
+                out_rel, (nval, nidx, nalpha) = fused(
+                    layout, tuple(factors), d, plan=plan, config=config,
+                    smax=smax, next_mode=nxt)
+            else:
+                out_rel = backend(layout, tuple(factors), d, plan=plan,
+                                  config=config)
+        with jax.named_scope("remap"):
+            if fused is not None:
+                nval = nval.astype(val.dtype)
+                nidx = nidx.astype(idx.dtype)
+            else:
+                # Alg. 3: conflict-free scatter into the mode-(d+1) layout
+                # (pads parked at S_max -> dropped); slots beyond S_{d+1}
+                # stay empty.
+                dst = jnp.where(alive, al[:, nxt], smax)
+                nval, nidx, nalpha = unpack_slots(scatter_slots(
+                    dst, pack_slots(v, ix, al), empty_slots(smax, n)))
+        with jax.named_scope("ec"):
+            # un-relabel -> (I_d, R)
+            out = jnp.take(out_rel, relabels[d], axis=0)
         if fold is not None:
-            factors, carry = fold(d, out, factors, carry)
+            with jax.named_scope("fold"):
+                factors, carry = fold(d, out, factors, carry)
         if pad_out_to is not None:
             out = jnp.pad(out, ((0, pad_out_to - plan.dim), (0, 0)))
         return (nval, nidx, nalpha), out, factors, carry
@@ -292,14 +331,28 @@ def _build_scan(state: EngineState, fold: FoldFn | None):
     return run
 
 
-def _scan_fn(state: EngineState, fold: FoldFn | None):
-    """The jitted all-modes program for ``state``'s static aux (cached)."""
+def _scan_args(state: EngineState, factors, carry) -> tuple:
+    """The all-modes program's arguments for ``state``."""
+    return ((state.val, state.idx, state.alpha), state.relabel, state.sched,
+            tuple(factors), carry)
+
+
+def _arg_shape(x) -> jax.ShapeDtypeStruct:
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding,
+                                weak_type=getattr(x, "weak_type", False))
+
+
+def _scan_fn(state: EngineState, fold: FoldFn | None, args: tuple):
+    """The jitted all-modes program for ``state``'s static aux (cached);
+    a new program records the shapes of ``args`` for :func:`op_scopes`."""
     key = ("all_modes", state.aux_key(), fold)
     fn = _JIT_CACHE.get(key)
     if fn is None:
         donate = (0,) if state.config.resolve_donate() else ()
         fn = _JIT_CACHE[key] = jax.jit(_build_scan(state, fold),
                                        donate_argnums=donate)
+        _SCAN_ARGS[key] = jax.tree.map(_arg_shape, args)
     return fn
 
 
@@ -318,15 +371,14 @@ def all_modes(state: EngineState, factors: Sequence[jax.Array], *,
     the scan right after each mode's output, which is how an ALS sweep
     stays a single traced program.
     """
-    fn = _scan_fn(state, fold)
+    args = _scan_args(state, factors, carry)
+    fn = _scan_fn(state, fold, args)
     _c = _chaos.active()
     if _c is not None:
         _c.on_dispatch(state.config.backend)
     DISPATCH_COUNTS["all_modes"] += 1
     with span("engine.dispatch", kind="all_modes", start_mode=state.mode):
-        layout3, outs, out_factors, out_carry = fn(
-            (state.val, state.idx, state.alpha), state.relabel, state.sched,
-            tuple(factors), carry)
+        layout3, outs, out_factors, out_carry = fn(*args)
     nval, nidx, nalpha = layout3
     next_state = state.replace(val=nval, idx=nidx, alpha=nalpha)
     if fold is None:
@@ -338,8 +390,7 @@ def scan_jaxpr(state: EngineState, factors: Sequence[jax.Array],
                fold: FoldFn | None = None, carry=None):
     """Jaxpr of the all-modes program (tests assert it is one scan)."""
     return jax.make_jaxpr(_build_scan(state, fold))(
-        (state.val, state.idx, state.alpha), state.relabel, state.sched,
-        tuple(factors), carry)
+        *_scan_args(state, factors, carry))
 
 
 def scan_hlo(state: EngineState, factors: Sequence[jax.Array],
@@ -348,11 +399,109 @@ def scan_hlo(state: EngineState, factors: Sequence[jax.Array],
     compiled it for the state's backend (Pallas kernels compiled through
     Mosaic appear as ``tpu_custom_call``). Same jitted function, so a
     persistent compilation cache serves the compile."""
-    return _scan_fn(state, fold).lower(
-        (state.val, state.idx, state.alpha), state.relabel, state.sched,
-        tuple(factors), carry).compile().as_text()
+    args = _scan_args(state, factors, carry)
+    return _compiled_text(_scan_fn(state, fold, args), args)
+
+
+def _compiled_text(fn, args) -> str:
+    return fn.lower(*args).compile().as_text()
+
+
+class OpScope(NamedTuple):
+    """Where one instruction of a sweep program sits: the mode's step and
+    its named scope (``ec``, ``remap`` or ``fold``). ``crossed`` holds the
+    other scopes a fusion took ops from; it is charged to its root's."""
+
+    mode: int
+    scope: str
+    crossed: frozenset = frozenset()
+
+
+_SCOPE = re.compile(r"(?:^|/)mode(\d+)/(ec|remap|fold)(?:/|$)")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[^\s(]+) ")
+_INSTRUCTION = re.compile(
+    r"^\s+(ROOT )?(%[^\s=]+) = (.+?) ([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=(%[^\s,]+)")
+_OP_SCOPES: dict = {}   # _JIT_CACHE key -> that program's scopes
+
+
+def op_scopes() -> dict[str, OpScope]:
+    """The named scope of every scoped instruction of each all-modes
+    program ``all_modes`` has built, from the compiled module's
+    ``metadata={op_name=...}``.
+
+    Keyed by the instruction's name and result shape
+    (``%fusion.15 = s32[3309568,9]{0,1:T(4,128)}``), the way each op event
+    of a device trace begins: names alone repeat across programs. Each
+    program is compiled again from the argument shapes of its first call,
+    with the same jitted function, so a compilation cache serves it.
+    """
+    out: dict[str, OpScope] = {}
+    for key, args in list(_SCAN_ARGS.items()):
+        fn = _JIT_CACHE.get(key)
+        if fn is None:
+            continue
+        if key not in _OP_SCOPES:
+            _OP_SCOPES[key] = _hlo_scopes(_compiled_text(fn, args))
+        out.update(_OP_SCOPES[key])
+    return out
+
+
+class _Instr(NamedTuple):
+    head: str              # "%name = shape"
+    root: bool
+    scope: tuple | None    # (mode, scope) of its op_name
+    callee: str | None     # the fused computation of a fusion
+
+
+def _hlo_scopes(text: str) -> dict[str, OpScope]:
+    """:func:`op_scopes` of one compiled module's text. Instructions of
+    fused computations run inside their fusion: a fusion takes the scope
+    of its fused root, else its own."""
+    comps: dict[str, list[_Instr]] = {}
+    body = None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head and line.rstrip().endswith("{"):
+            body = comps.setdefault(head.group(1), [])
+        elif body is not None and (m := _INSTRUCTION.match(line)):
+            name = _OP_NAME.search(line)
+            sc = _SCOPE.search(name.group(1)) if name else None
+            callee = _CALLS.search(line) if m.group(4) == "fusion" else None
+            body.append(_Instr(f"{m.group(2)} = {m.group(3)}",
+                               bool(m.group(1)),
+                               (int(sc.group(1)), sc.group(2)) if sc
+                               else None,
+                               callee.group(1) if callee else None))
+
+    def scopes_in(comp: str) -> set:
+        found = set()
+        for ins in comps.get(comp, ()):
+            if ins.scope:
+                found.add(ins.scope[1])
+            if ins.callee:
+                found |= scopes_in(ins.callee)
+        return found
+
+    fused = {ins.callee for body in comps.values() for ins in body
+             if ins.callee}
+    out: dict[str, OpScope] = {}
+    for comp, body in comps.items():
+        if comp in fused:
+            continue
+        for ins in body:
+            scope, crossed = ins.scope, set()
+            if ins.callee:
+                scope = next((i.scope for i in comps.get(ins.callee, ())
+                              if i.root and i.scope), scope)
+                if scope:
+                    crossed = scopes_in(ins.callee) - {scope[1]}
+            if scope:
+                out[ins.head] = OpScope(*scope, frozenset(crossed))
+    return out
 
 
 __all__ = ["init", "mttkrp", "all_modes", "scan_jaxpr", "scan_hlo",
-           "reset_counters",
-           "TRACE_COUNTS", "DISPATCH_COUNTS", "FoldFn"]
+           "op_scopes", "OpScope", "reset_counters",
+           "TRACE_COUNTS", "DISPATCH_COUNTS", "ROW_COPIES", "FoldFn"]

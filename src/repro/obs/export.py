@@ -1,29 +1,23 @@
-"""Trace/metrics exporters: Chrome-trace JSON, JSONL event log, manifest.
+"""Trace/metrics exporter: Chrome-trace JSON.
 
 The Chrome trace (``chrome_trace`` / ``write_chrome_trace``) follows the
 Trace Event Format's "JSON object" flavor — a ``traceEvents`` list of
 complete (``"ph": "X"``) duration events plus thread-name metadata and
 one ``"C"`` counter sample per counter metric — and loads directly into
 Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.  Extra
-top-level keys carry the run manifest and a metrics snapshot, which the
-CI ``obs-smoke`` gate reads back (span-derived vs count-derived overlap
-agreement) without re-running anything.
-
-``write_jsonl`` is the greppable flat log (one JSON object per span);
-``run_manifest`` records what produced the trace (jax version, backend,
-devices, PlanSpec knobs, dataset signature).
+top-level keys carry the caller's run manifest and a metrics snapshot,
+which the CI ``obs-smoke`` gate reads back (span-derived vs count-derived
+overlap agreement) without re-running anything.
 """
 from __future__ import annotations
 
 import json
 import os
-import time
 
 from .metrics import REGISTRY, MetricsRegistry
 from .trace import SpanRecord, Tracer, get_tracer
 
-__all__ = ["chrome_trace", "write_chrome_trace", "write_jsonl",
-           "run_manifest", "validate_chrome_trace"]
+__all__ = ["chrome_trace", "write_chrome_trace", "validate_chrome_trace"]
 
 
 def _jsonable(value):
@@ -41,42 +35,6 @@ def _jsonable(value):
         except Exception:
             pass
     return repr(value)
-
-
-def run_manifest(spec=None, dataset_signature=None, extra=None) -> dict:
-    """What produced this trace: runtime versions, backend + devices,
-    the PlanSpec/ExecutionConfig knobs, and the dataset's sparsity
-    signature (all optional and degraded gracefully — obs itself has no
-    hard deps)."""
-    import platform
-    import sys
-
-    manifest: dict = {
-        "unix_time": time.time(),
-        "python": sys.version.split()[0],
-        "platform": platform.platform(),
-        "argv": list(sys.argv),
-        "pid": os.getpid(),
-    }
-    try:
-        import jax
-
-        manifest["jax_version"] = jax.__version__
-        manifest["jax_backend"] = jax.default_backend()
-        manifest["devices"] = [str(d) for d in jax.local_devices()]
-    except Exception:  # pragma: no cover - jax is a repo-wide dep
-        pass
-    if spec is not None:
-        import dataclasses
-
-        manifest["plan_spec"] = (
-            dataclasses.asdict(spec) if dataclasses.is_dataclass(spec)
-            else _jsonable(spec))
-    if dataset_signature is not None:
-        manifest["dataset_signature"] = _jsonable(dataset_signature)
-    if extra:
-        manifest.update({str(k): _jsonable(v) for k, v in extra.items()})
-    return manifest
 
 
 def chrome_trace(tracer: Tracer | None = None,
@@ -130,7 +88,7 @@ def chrome_trace(tracer: Tracer | None = None,
         "traceEvents": events,
         "displayTimeUnit": "ms",
         "metadata": {
-            "manifest": manifest if manifest is not None else run_manifest(),
+            "manifest": manifest if manifest is not None else {},
             "metrics": metrics,
             "span_count": len(spans),
         },
@@ -189,24 +147,3 @@ def write_chrome_trace(path: str, tracer: Tracer | None = None,
         f.write("\n")
     os.replace(tmp, path)
     return trace
-
-
-def write_jsonl(path: str, tracer: Tracer | None = None) -> int:
-    """Flat span log: one JSON object per span, start-ordered. Returns
-    the number of spans written."""
-    tracer = tracer or get_tracer()
-    spans = tracer.spans() if tracer else ()
-    parent = os.path.dirname(os.path.abspath(path))
-    os.makedirs(parent, exist_ok=True)
-    tmp = os.path.join(parent, f".tmp-jsonl-{os.getpid()}")
-    with open(tmp, "w") as f:
-        for s in spans:
-            f.write(json.dumps({
-                "name": s.name, "span_id": s.span_id,
-                "parent_id": s.parent_id, "thread": s.thread_name,
-                "start_ns": s.start_ns, "dur_ns": s.duration_ns,
-                "attrs": {str(k): _jsonable(v) for k, v in s.attrs.items()},
-            }))
-            f.write("\n")
-    os.replace(tmp, path)
-    return len(spans)

@@ -1,9 +1,9 @@
 """Peak-memory probes (host RSS + device allocator high-water mark).
 
 Lives in ``repro.obs`` so library code — ``StreamStats.as_row()``, the
-run manifest, the report — can record residency without importing bench
-helpers; ``benchmarks.common`` re-exports :func:`memory_probe` for the
-existing figure scripts.
+report — can record residency without importing bench helpers;
+``benchmarks.common`` re-exports :func:`memory_probe` for the existing
+figure scripts.
 """
 from __future__ import annotations
 

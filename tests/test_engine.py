@@ -11,6 +11,8 @@ Covers the acceptance criteria of the engine redesign:
     modes for nmodes 3..6, works from any start mode, and ``reset()``
     restores mode 0 (regression for the removed mode-0 assertion).
 """
+import contextlib
+import re
 import warnings
 
 import jax
@@ -21,6 +23,7 @@ import pytest
 from repro import engine
 from repro.core import (MTTKRPExecutor, build_flycoo, cp_als,
                         cp_als_reference, init_factors, mttkrp_ref)
+from repro.core.cpd import _als_fold
 from repro.engine import EngineState, ExecutionConfig
 
 DIMS_BY_NMODES = {
@@ -461,3 +464,108 @@ def test_cp_als_with_config_matches_reference():
     with pytest.raises(ValueError, match="not both"):
         cp_als(t, rank=4, iters=1, config=ExecutionConfig(),
                backend="pallas")
+
+
+# --------------------------------------------------------------------------
+# What the sweep program tells about itself: named scopes per mode,
+# op_scopes, and the EC kernels' row-copy gauge.
+# --------------------------------------------------------------------------
+_METADATA = re.compile(r", metadata=\{[^}]*\}")
+# the module's source-location tables, each up to its blank line
+_SOURCE_TABLES = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n.*?\n\n",
+    re.M | re.S)
+
+
+def _without_metadata(text: str) -> str:
+    return _METADATA.sub("", _SOURCE_TABLES.sub("", text))
+
+
+def _sweep(backend, seed=21, **cfg):
+    dims = DIMS_BY_NMODES[4]
+    _, _, t = _tensor(seed, dims, 700, rows_pp=4, block_p=8)
+    state = engine.init(t, ExecutionConfig(backend=backend, interpret=True,
+                                           donate=False, **cfg))
+    factors = tuple(init_factors(jax.random.PRNGKey(4), dims, 8))
+    return t, state, factors, jnp.ones((8,), jnp.float32)
+
+
+def test_sweep_scopes_in_compiled_hlo():
+    _, state, factors, lam = _sweep("xla")
+    text = engine.scan_hlo(state, factors, fold=_als_fold, carry=lam)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for d in range(state.nmodes):
+        for scope in ("ec", "remap", "fold"):
+            assert any(f"/mode{d}/{scope}/" in n for n in names), (d, scope)
+
+
+@pytest.mark.parametrize("backend,fuse_remap", [
+    ("xla", True), ("pallas_fused", False), ("pallas_fused", True)])
+def test_sweep_scopes_change_only_metadata(monkeypatch, backend, fuse_remap):
+    """Without the named scopes the optimized program is the same, apart
+    from metadata: instruction for instruction, names included."""
+    _, state, factors, lam = _sweep(backend, fuse_remap=fuse_remap)
+    args = engine.api._scan_args(state, factors, lam)
+
+    def compiled():
+        fn = jax.jit(engine.api._build_scan(state, _als_fold))
+        return fn.lower(*args).compile().as_text()
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    assert "/mode1/ec/" in scoped and "/mode1/" not in plain
+    assert "FileLocations" in scoped and "FileLocations" not in \
+        _without_metadata(scoped)
+    assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+def test_op_scopes_map_the_slot_scatters_to_remap(monkeypatch):
+    monkeypatch.setattr(engine.api, "_JIT_CACHE", {})
+    monkeypatch.setattr(engine.api, "_SCAN_ARGS", {})
+    monkeypatch.setattr(engine.api, "_OP_SCOPES", {})
+    assert engine.api.op_scopes() == {}
+    _, state, factors, lam = _sweep("xla")
+    engine.all_modes(state, factors, fold=_als_fold, carry=lam)
+    scopes = engine.api.op_scopes()
+    # the shapes recorded at the first call compile the program it ran
+    text = engine.scan_hlo(state, factors, fold=_als_fold, carry=lam)
+    (key,) = engine.api._SCAN_ARGS
+    assert engine.api._compiled_text(engine.api._JIT_CACHE[key],
+                                     engine.api._SCAN_ARGS[key]) == text
+    # every fusion around a scatter: the (S, 2N+1) slot-record moves are
+    # each mode's remap, the f32 row sums its EC
+    comps = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(%\S+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    scatter_comps = {c for c, body in comps.items() if " scatter(" in body}
+    heads = re.findall(
+        r"^\s+(?:ROOT )?(%\S+ = \S+) fusion\(.*calls=(%[^,\s]+)", text, re.M)
+    records = [h for h, c in heads if c in scatter_comps
+               and re.match(r"%\S+ = s32\[\d+,9\]", h)]
+    sums = [h for h, c in heads if c in scatter_comps
+            and re.match(r"%\S+ = f32\[", h)]
+    assert len(records) == state.nmodes and sums
+    assert sorted((scopes[h].mode, scopes[h].scope) for h in records) == \
+        [(d, "remap") for d in range(state.nmodes)]
+    assert {scopes[h].scope for h in sums} == {"ec"}
+    assert {s.scope for s in scopes.values()} == {"ec", "remap", "fold"}
+
+
+@pytest.mark.parametrize("dedup", [True, False])
+def test_engine_row_copies_equal_the_nuniq_sums(dedup):
+    t, state, _, _ = _sweep("pallas_fused", seed=22, dedup=dedup)
+    for d in range(t.nmodes):
+        nuniq = np.asarray(state.sched[d].nuniq)
+        assert engine.api.ROW_COPIES[d] == int(nuniq.sum()) > 0
+        if dedup:
+            assert engine.api.ROW_COPIES[d] == int(t.dedup_tables(d)[2].sum())
+            assert t._dedup_cache[("row_copies", d)] == int(nuniq.sum())
+        else:
+            p = t.plans[d]
+            assert engine.api.ROW_COPIES[d] == \
+                p.nblocks * p.block_p * (t.nmodes - 1)
+    # a backend that stages no rows through dedup tables issues none
+    engine.init(t, ExecutionConfig(backend="xla"))
+    assert [engine.api.ROW_COPIES[d] for d in range(t.nmodes)] == \
+        [0] * t.nmodes

@@ -258,23 +258,6 @@ def test_validate_rejects_malformed():
     assert any("span_id" in e for e in errs)
 
 
-def test_jsonl_export(tracer, tmp_path):
-    with obs.span("a"):
-        pass
-    path = tmp_path / "spans.jsonl"
-    assert obs.write_jsonl(str(path), tracer) == 1
-    rec = json.loads(path.read_text().strip())
-    assert rec["name"] == "a" and rec["dur_ns"] >= 0
-
-
-def test_run_manifest_contents():
-    m = obs.run_manifest(spec=engine.PlanSpec(),
-                        dataset_signature=((4, 5), 17))
-    assert m["jax_version"] == jax.__version__
-    assert m["plan_spec"]["backend"] == "xla"
-    assert m["dataset_signature"] == [[4, 5], 17]
-
-
 def test_env_var_enables(tmp_path):
     import subprocess
     import sys
@@ -289,6 +272,77 @@ def test_env_var_enables(tmp_path):
     trace = json.loads(out.read_text())
     assert obs.validate_chrome_trace(trace) == []
     assert any(e.get("name") == "x" for e in trace["traceEvents"])
+
+
+# --------------------------------------------------------------------------
+# The spans of a CPD-ALS start that the chip benchmark reads.
+# --------------------------------------------------------------------------
+def _flycoo():
+    idx, val, dims = _coo()
+    return build_flycoo(idx.astype(np.int32), val, dims, rows_pp=4,
+                        block_p=8)
+
+
+def test_engine_upload_span_after_init_with_its_bytes(tracer):
+    t = _flycoo()
+    state = engine.init(t, engine.ExecutionConfig(backend="pallas_fused",
+                                                  interpret=True))
+    spans = {s.name: s for s in tracer.spans()}
+    init, up = spans["engine.init"], spans["engine.upload"]
+    assert up.parent_id is None and up.start_ns >= init.end_ns
+    # every array device_place hands over: the layout, the relabel tables
+    # and each mode's schedule and dedup tables
+    n, smax = t.nmodes, state.smax
+    expect = smax * 4 * (1 + 2 * n)
+    expect += sum(4 * p.row_relabel.size for p in t.plans)
+    for d, p in enumerate(t.plans):
+        uidx, upos, nuniq = t.dedup_tables(d)
+        expect += 4 * (p.block_part.size + uidx.size + upos.size
+                       + nuniq.size)
+    assert up.attrs["bytes"] == expect
+
+
+def test_engine_upload_waits_only_while_tracing(monkeypatch):
+    waited = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waited.append(x) or x)
+    t = _flycoo()
+    cfg = engine.ExecutionConfig(backend="xla")
+    prev = obs.disable()
+    try:
+        engine.init(t, cfg)
+        assert waited == []
+        tracer = obs.enable(obs.Tracer(xla_annotations=False))
+        state = engine.init(t, cfg)
+    finally:
+        obs.disable()
+        if prev is not None:
+            obs.enable(prev)
+    assert len(waited) == 1 and waited[0] is state
+    assert [s.name for s in tracer.spans()].count("engine.upload") == 1
+
+
+def test_cpd_start_and_fit_spans_nest(tracer):
+    from repro.core import cp_als
+
+    res = cp_als(_flycoo(), 4, iters=3,
+                 config=engine.ExecutionConfig(backend="xla"))
+    spans = tracer.spans()
+    (start,) = [s for s in spans if s.name == "cpd.start"]
+    assert start.parent_id is None
+    for name in ("engine.init", "engine.upload"):
+        (s,) = [s for s in spans if s.name == name]
+        assert s.parent_id == start.span_id
+    sweeps = [s for s in spans if s.name == "cpd.sweep"]
+    fits = [s for s in spans if s.name == "cpd.fit"]
+    assert len(sweeps) == len(fits) == 3
+    assert start.end_ns <= sweeps[0].start_ns
+    for sweep, fit, value in zip(sweeps, fits, res.fits):
+        assert fit.parent_id == sweep.span_id
+        assert sweep.attrs["fit"] == value
+        (dispatch,) = [s for s in spans if s.name == "engine.dispatch"
+                       and s.parent_id == sweep.span_id]
+        assert dispatch.end_ns <= fit.start_ns <= fit.end_ns <= sweep.end_ns
 
 
 # --------------------------------------------------------------------------
