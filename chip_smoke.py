@@ -272,7 +272,8 @@ def four_chips(jax, devices, seed: int) -> None:
     mesh = make_mesh((4,), ("data",))
     t0 = time.perf_counter()
     dstate = jax.block_until_ready(
-        engine.dist.shard_state(engine.init(tensor, cfg), mesh))
+        engine.dist.shard_state(engine.init(tensor, cfg, _rotating=True),
+                                mesh))
     log(f"engine.init + shard_state {time.perf_counter() - t0:.1f} s")
     mesh_devices = set(mesh.devices.flat)
     for leaf in jax.tree.leaves((dstate.val, dstate.idx, dstate.alpha,

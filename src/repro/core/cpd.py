@@ -193,7 +193,8 @@ def cp_als(
         raise ValueError("dist config given without a mesh")
 
     def build_state(cfg):
-        st = engine.init(tensor, cfg)
+        # shard_state re-lays the rotating layout across the mesh
+        st = engine.init(tensor, cfg, _rotating=mesh is not None)
         if mesh is not None:
             st = engine.dist.shard_state(st, mesh, dist)
         return st

@@ -84,8 +84,9 @@ class DistributedMTTKRP:
         self.config = ExecutionConfig()
         self.dist = DistConfig(data_axis=data_axis, model_axis=model_axis,
                                exchange=exchange)
-        self._dstate = shard_state(_engine.init(tensor, self.config), mesh,
-                                   self.dist)
+        self._dstate = shard_state(
+            _engine.init(tensor, self.config, _rotating=True), mesh,
+            self.dist)
         self.row_relabel = list(self._dstate.relabel)
 
     # ------------------------------------------------------------ state view
@@ -118,5 +119,6 @@ class DistributedMTTKRP:
 
     def reset(self) -> None:
         """Return to the pristine start-mode sharded layout."""
-        self._dstate = shard_state(_engine.init(self.tensor, self.config),
-                                   self.mesh, self.dist)
+        self._dstate = shard_state(
+            _engine.init(self.tensor, self.config, _rotating=True),
+            self.mesh, self.dist)

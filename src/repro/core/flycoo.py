@@ -113,9 +113,9 @@ class FlycooTensor:
     indices: np.ndarray           # (nnz, N) int32, canonical order
     values: np.ndarray            # (nnz,) float32, canonical order
     plans: list[ModePlan]
-    # per-mode dedup tables and their row-copy sums, built lazily once
-    # (engine init + dma_row_model + the autotuner's exact cost stage all
-    # consume the same tables)
+    # per-mode dedup tables, their row-copy sums and the pinned (val, lrow)
+    # layouts, built lazily once (engine init + dma_row_model + the
+    # autotuner's exact cost stage all consume the same tables)
     _dedup_cache: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
 
@@ -128,24 +128,23 @@ class FlycooTensor:
         return int(self.values.shape[0])
 
     # ---------------------------------------------------------------- layout
-    def layout_arrays(self, d: int) -> dict[str, np.ndarray]:
-        """Materialize the mode-d kernel layout arrays (val/idx/lrow/dst)."""
-        plan = self.plans[d]
-        nxt = self.plans[(d + 1) % self.nmodes]
-        S = plan.padded_nnz
-        val = np.zeros(S, dtype=np.float32)
-        idx = np.zeros((S, self.nmodes), dtype=np.int32)
-        lrow = np.full(S, -1, dtype=np.int32)
-        dst = np.full(S, -1, dtype=np.int32)
-
-        slots = plan.slot_of_elem
-        val[slots] = self.values
-        idx[slots] = self.indices
-        # local row within owning partition, in relabeled space
-        rel = plan.row_relabel[self.indices[:, d]].astype(np.int64)
-        lrow[slots] = (rel % plan.rows_pp).astype(np.int32)
-        dst[slots] = nxt.slot_of_elem.astype(np.int32)
-        return {"val": val, "idx": idx, "lrow": lrow, "dst": dst}
+    def pinned_layout(self, d: int) -> tuple[np.ndarray, np.ndarray]:
+        """``(val (S_d,) f32, lrow (S_d,) i32)`` of the mode-``d`` kernel
+        layout: the values (0 in pads) and each slot's relabeled row local
+        to its partition (-1 in pads) — all the compact fused kernel reads
+        of a layout. Built once per mode and memoized with the dedup
+        tables."""
+        key = ("pinned", d)
+        cached = self._dedup_cache.get(key)
+        if cached is None:
+            plan = self.plans[d]
+            val = np.zeros(plan.padded_nnz, dtype=np.float32)
+            lrow = np.full(plan.padded_nnz, -1, dtype=np.int32)
+            val[plan.slot_of_elem] = self.values
+            rel = plan.row_relabel[self.indices[:, d]].astype(np.int64)
+            lrow[plan.slot_of_elem] = (rel % plan.rows_pp).astype(np.int32)
+            cached = self._dedup_cache[key] = (val, lrow)
+        return cached
 
     def _slot_rows(self, d: int) -> np.ndarray:
         """(N-1, S_d) int32 factor row per mode-``d`` slot for every input

@@ -169,7 +169,7 @@ class MTTKRPExecutor:
         # must interpret anyway; map it to the config's auto mode.
         self.config = ExecutionConfig(backend=backend,
                                       interpret=True if interpret else None)
-        self._state = _engine.init(tensor, self.config)
+        self._state = _engine.init(tensor, self.config, _rotating=True)
         # note: out_user[v] = out_rel[row_relabel[v]] (relabel is old->new)
         self.row_relabel = list(self._state.relabel)
 
@@ -206,4 +206,5 @@ class MTTKRPExecutor:
     def reset(self) -> None:
         """Return to the pristine mode-0 layout (re-derives device state
         from the host tensor; cheap relative to preprocessing)."""
-        self._state = _engine.init(self.tensor, self.config)
+        self._state = _engine.init(self.tensor, self.config,
+                                   _rotating=True)

@@ -12,7 +12,9 @@ sequence, each step a ``lax.switch`` into that mode's statically-shaped
 elementwise computation + dynamic remap (Alg. 2 + 3). There is no per-mode
 Python dispatch, the T_in/T_out layout swap is the scan carry (donated on
 TPU/GPU), and the rotation may start at *any* resident mode — the old
-executor's ``current_mode == 0`` restriction is gone.
+executor's ``current_mode == 0`` restriction is gone. On a *pinned* state
+(see :mod:`repro.engine.state`) each step reads its mode's own
+``(val, lrow)`` and nothing remaps: the carry is the factors alone.
 
 An optional ``fold`` callback runs inside the scan after each mode's
 MTTKRP with that mode's output — this is how CPD-ALS updates factor
@@ -34,9 +36,9 @@ from repro.obs.trace import get_tracer, span
 from repro.resilience import chaos as _chaos
 
 from .backends import (compute_lrow, empty_slots, get_backend, pack_slots,
-                       scatter_slots, unpack_slots)
+                       pins_layout, scatter_slots, unpack_slots)
 from .config import ExecutionConfig
-from .state import (EngineState, ModeSched, ModeStatic,
+from .state import (EngineState, ModeSched, ModeStatic, PinnedMode,
                     mode_static_from_plan)
 
 # Fold callback: fold(mode, out_d, factors, carry) -> (factors, carry),
@@ -60,6 +62,10 @@ DISPATCH_COUNTS = REGISTRY.counter(
 # row-copy loop (0 where the backend stages no rows through dedup tables).
 ROW_COPIES = REGISTRY.gauge(
     "engine_row_copies", "factor-row DMAs per EC kernel pass, per mode")
+# Slot records the mode-d step's Alg. 3 remap moves: S_max on a rotating
+# state, 0 on a pinned one.
+REMAP_SLOTS = REGISTRY.gauge(
+    "engine_remap_slots", "slot records the remap moves per step, per mode")
 
 _JIT_CACHE: dict = {}
 # Argument shapes (jax.ShapeDtypeStruct, with shardings) of each all-modes
@@ -76,7 +82,8 @@ def reset_counters() -> None:
 # init
 # --------------------------------------------------------------------------
 def init(tensor, config: ExecutionConfig | None = None,
-         start_mode: int = 0, *, cache=None) -> EngineState:
+         start_mode: int = 0, *, cache=None,
+         _rotating: bool = False) -> EngineState:
     """Build the device-resident engine state for ``tensor``.
 
     ``tensor`` is a prebuilt :class:`~repro.core.flycoo.FlycooTensor` (its
@@ -84,8 +91,14 @@ def init(tensor, config: ExecutionConfig | None = None,
     — then the FLYCOO plans are built here under ``config``'s kappa policy,
     through ``cache`` (a :class:`repro.core.plancache.PlanCache`) when one
     is given so repeated/streaming inits skip ``plan_mode``.
-    The returned state holds the ``start_mode`` layout, padded to the
-    uniform slot count ``S_max`` so every mode shares one pytree shape.
+
+    Where the backend reads only ``val`` and ``lrow`` of the layout
+    (:func:`~repro.engine.backends.pins_layout`) the state pins each
+    mode's ``(val, lrow)``; otherwise it holds the ``start_mode`` layout,
+    padded to the uniform slot count ``S_max`` so every mode shares one
+    pytree shape, and remaps it every step. ``_rotating=True`` asks for the
+    rotating layout whatever the backend, for callers that re-lay it
+    (``engine.dist.shard_state``) or hand it out (the deprecated shims).
     """
     config = config or ExecutionConfig()
     with span("engine.init", start_mode=start_mode) as sp:
@@ -96,27 +109,38 @@ def init(tensor, config: ExecutionConfig | None = None,
                 f"start_mode {start_mode} out of range for {n} modes")
         statics = tuple(mode_static_from_plan(p) for p in tensor.plans)
         smax = max(s.padded_nnz for s in statics)
+        pinned = not _rotating and pins_layout(config, statics)
         sp.set("nmodes", n)
         sp.set("smax", smax)
+        sp.set("layout", "pinned" if pinned else "rotating")
+        for d in range(n):
+            REMAP_SLOTS.set(d, 0 if pinned else smax)
 
         with span("engine.host_layout", mode=start_mode):
-            base = tensor.plans[start_mode]
-            val = np.zeros(smax, dtype=np.float32)
-            idx = np.zeros((smax, n), dtype=np.int32)
-            alpha = np.full((smax, n), -1, dtype=np.int32)
-            val[base.slot_of_elem] = tensor.values
-            idx[base.slot_of_elem] = tensor.indices
-            for d in range(n):
-                alpha[base.slot_of_elem, d] = \
-                    tensor.plans[d].slot_of_elem.astype(np.int32)
+            if pinned:
+                host = [tensor.pinned_layout(d) for d in range(n)]
+            else:
+                base = tensor.plans[start_mode]
+                val = np.zeros(smax, dtype=np.float32)
+                idx = np.zeros((smax, n), dtype=np.int32)
+                alpha = np.full((smax, n), -1, dtype=np.int32)
+                val[base.slot_of_elem] = tensor.values
+                idx[base.slot_of_elem] = tensor.indices
+                for d in range(n):
+                    alpha[base.slot_of_elem, d] = \
+                        tensor.plans[d].slot_of_elem.astype(np.int32)
 
         with span("engine.sched_tables"):
             sched = tuple(_mode_sched(tensor, d, config) for d in range(n))
         with span("engine.device_place"):
+            if pinned:
+                layout = dict(val=None, idx=None, alpha=None, pinned=tuple(
+                    PinnedMode(jnp.asarray(v), jnp.asarray(lr))
+                    for v, lr in host))
+            else:
+                layout = dict(val=jnp.asarray(val), idx=jnp.asarray(idx),
+                              alpha=jnp.asarray(alpha))
             state = EngineState(
-                val=jnp.asarray(val),
-                idx=jnp.asarray(idx),
-                alpha=jnp.asarray(alpha),
                 relabel=tuple(jnp.asarray(p.row_relabel)
                               for p in tensor.plans),
                 sched=sched,
@@ -124,6 +148,7 @@ def init(tensor, config: ExecutionConfig | None = None,
                 dims=tensor.dims,
                 statics=statics,
                 config=config,
+                **layout,
             )
     tracer = get_tracer()
     if tracer is not None:
@@ -179,15 +204,17 @@ def _as_flycoo(tensor, config: ExecutionConfig, cache=None):
 # --------------------------------------------------------------------------
 def _mode_branch(d: int, *, statics: Sequence[ModeStatic], smax: int,
                  config: ExecutionConfig, fold: FoldFn | None,
-                 pad_out_to: int | None):
+                 pad_out_to: int | None, pinned: bool = False):
     """Build the traced step for (static) mode ``d``.
 
-    Returns a function (layout3, relabels, sched, factors, carry) ->
-    ((nval, nidx, nalpha), out, factors, carry) where ``layout3`` is the
-    S_max-padded (val, idx, alpha) triple, ``sched`` the per-mode schedule
-    tables, and ``out`` is the mode-``d`` MTTKRP in user row space,
-    zero-padded to ``pad_out_to`` rows when a uniform stacked shape is
-    needed (the scan path).
+    Returns a function (layout, relabels, sched, factors, carry) ->
+    (next_layout, out, factors, carry). On a rotating state ``layout`` is
+    the S_max-padded (val, idx, alpha) triple and ``next_layout`` its remap
+    to mode ``d + 1``; on a pinned one ``layout`` is every mode's
+    :class:`PinnedMode` and ``next_layout`` is ``None``. ``sched`` holds the
+    per-mode schedule tables, and ``out`` is the mode-``d`` MTTKRP in user
+    row space, zero-padded to ``pad_out_to`` rows when a uniform stacked
+    shape is needed (the scan path).
     """
     plan = statics[d]
     n = len(statics)
@@ -196,18 +223,19 @@ def _mode_branch(d: int, *, statics: Sequence[ModeStatic], smax: int,
     backend = get_backend(config)
     # Fusing backends (e.g. ``pallas_fused``) emit the Alg. 3 remap scatter
     # inside the EC kernel pass; ``config.fuse_remap=False`` keeps the XLA
-    # scatter path as the comparison baseline.
+    # scatter path as the comparison baseline. A pinned layout has no
+    # remap to fuse.
     fused = (getattr(backend, "fused_remap", None)
-             if config.fuse_remap else None)
+             if config.fuse_remap and not pinned else None)
 
-    def step(layout3, relabels, sched, factors, carry):
+    def step(layout, relabels, sched, factors, carry):
         with jax.named_scope(f"mode{d}"):
-            return _step(layout3, relabels, sched, factors, carry)
+            return _step(layout, relabels, sched, factors, carry)
 
     # The scopes name each op's layer in the compiled program's metadata
     # (``op_scopes``); the ops keep the order they had without them, so
     # the optimized HLO differs in metadata only.
-    def _step(layout3, relabels, sched, factors, carry):
+    def _rotating_ec(layout3, relabels, sched, factors):
         val, idx, alpha = layout3
         with jax.named_scope("ec"):
             v, ix, al = val[:sd], idx[:sd], alpha[:sd]
@@ -236,6 +264,17 @@ def _mode_branch(d: int, *, statics: Sequence[ModeStatic], smax: int,
                 dst = jnp.where(alive, al[:, nxt], smax)
                 nval, nidx, nalpha = unpack_slots(scatter_slots(
                     dst, pack_slots(v, ix, al), empty_slots(smax, n)))
+        return out_rel, (nval, nidx, nalpha)
+
+    def _step(layout, relabels, sched, factors, carry):
+        if pinned:
+            nl = None
+            with jax.named_scope("ec"):
+                out_rel = backend({**layout[d]._asdict(),
+                                   **sched[d]._asdict()}, tuple(factors), d,
+                                  plan=plan, config=config)
+        else:
+            out_rel, nl = _rotating_ec(layout, relabels, sched, factors)
         with jax.named_scope("ec"):
             # un-relabel -> (I_d, R)
             out = jnp.take(out_rel, relabels[d], axis=0)
@@ -244,7 +283,7 @@ def _mode_branch(d: int, *, statics: Sequence[ModeStatic], smax: int,
                 factors, carry = fold(d, out, factors, carry)
         if pad_out_to is not None:
             out = jnp.pad(out, ((0, pad_out_to - plan.dim), (0, 0)))
-        return (nval, nidx, nalpha), out, factors, carry
+        return nl, out, factors, carry
 
     return step
 
@@ -256,7 +295,8 @@ def mttkrp(state: EngineState, factors: Sequence[jax.Array],
            mode: int | None = None):
     """MTTKRP for the resident mode + remap to the next; returns
     ``(out, next_state)``. ``mode`` (optional) must name the resident mode
-    — the layout physically *is* mode-``state.mode``'s."""
+    — the layout physically *is* mode-``state.mode``'s (a pinned state
+    steps the same way, though it holds every mode's)."""
     if mode is not None and mode != state.mode:
         raise ValueError(
             f"state holds the mode-{state.mode} layout; cannot compute "
@@ -267,25 +307,48 @@ def mttkrp(state: EngineState, factors: Sequence[jax.Array],
     if fn is None:
         step = _mode_branch(d, statics=state.statics, smax=state.smax,
                             config=state.config, fold=None,
-                            pad_out_to=None)
+                            pad_out_to=None,
+                            pinned=state.pinned is not None)
 
-        def run(layout3, relabels, sched, factors):
+        def run(layout, relabels, sched, factors):
             TRACE_COUNTS["mttkrp"] += 1  # trace-time side effect
-            nl, out, _, _ = step(layout3, relabels, sched, factors, None)
+            nl, out, _, _ = step(layout, relabels, sched, factors, None)
             return nl, out
 
-        donate = (0,) if state.config.resolve_donate() else ()
-        fn = _JIT_CACHE[key] = jax.jit(run, donate_argnums=donate)
+        fn = _JIT_CACHE[key] = jax.jit(run, donate_argnums=_donate(state))
     _c = _chaos.active()
     if _c is not None:
         _c.on_dispatch(state.config.backend)
     DISPATCH_COUNTS["mttkrp"] += 1
     with span("engine.dispatch", kind="mttkrp", mode=d):
-        (nval, nidx, nalpha), out = fn(
-            (state.val, state.idx, state.alpha), state.relabel, state.sched,
-            tuple(factors))
-    nxt = (d + 1) % state.nmodes
-    return out, state.replace(val=nval, idx=nidx, alpha=nalpha, mode=nxt)
+        nl, out = fn(_layout(state), state.relabel, state.sched,
+                     tuple(factors))
+    return out, _advance(state, nl).replace(mode=(d + 1) % state.nmodes)
+
+
+def _layout(state: EngineState):
+    """The layout a step reads: every mode's ``PinnedMode`` on a pinned
+    state, the (val, idx, alpha) triple on a rotating one."""
+    if state.pinned is not None:
+        return state.pinned
+    return (state.val, state.idx, state.alpha)
+
+
+def _advance(state: EngineState, next_layout) -> EngineState:
+    """``state`` holding the layout a program handed back (a pinned state
+    gets none: it keeps its own)."""
+    if next_layout is None:
+        return state
+    nval, nidx, nalpha = next_layout
+    return state.replace(val=nval, idx=nidx, alpha=nalpha)
+
+
+def _donate(state: EngineState) -> tuple:
+    """Donate the rotating layout into the program that remaps it; a pinned
+    layout is read again by every call."""
+    if state.pinned is None and state.config.resolve_donate():
+        return (0,)
+    return ()
 
 
 # --------------------------------------------------------------------------
@@ -300,41 +363,47 @@ def _build_scan(state: EngineState, fold: FoldFn | None):
     """
     n, m0, smax, imax = state.nmodes, state.mode, state.smax, state.imax
     dims = state.dims
+    pinned = state.pinned is not None
     seq = tuple((m0 + i) % n for i in range(n))
     branches = [
         _mode_branch(d, statics=state.statics, smax=smax,
-                     config=state.config, fold=fold, pad_out_to=imax)
+                     config=state.config, fold=fold, pad_out_to=imax,
+                     pinned=pinned)
         for d in range(n)
     ]
 
-    def run(layout3, relabels, sched, factors, carry):
+    def run(layout, relabels, sched, factors, carry):
         TRACE_COUNTS["all_modes"] += 1  # trace-time side effect
 
+        # A rotating layout is carried from step to step; a pinned one
+        # never changes, so the steps read it from outside the scan and
+        # its carry slot stays None.
         def body(sc, mode_t):
-            layout3, factors, carry = sc
+            moving, factors, carry = sc
             nl, out, factors, carry = lax.switch(
                 mode_t,
-                [lambda l3, f, c, b=b: b(l3, relabels, sched, f, c)
+                [lambda m, f, c, b=b: b(layout if pinned else m, relabels,
+                                        sched, f, c)
                  for b in branches],
-                layout3, factors, carry)
+                moving, factors, carry)
             return (nl, factors, carry), out
 
-        (layout3, factors, carry), outs = lax.scan(
-            body, (layout3, factors, carry),
+        (moving, factors, carry), outs = lax.scan(
+            body, (None if pinned else layout, factors, carry),
             jnp.asarray(seq, dtype=jnp.int32))
         # outs[i] is mode seq[i], padded to imax rows; hand back per-mode
         # views in mode order, statically sliced to each I_d.
         by_mode = tuple(
             outs[seq.index(d)][: dims[d]] for d in range(n))
-        return layout3, by_mode, factors, carry
+        return moving, by_mode, factors, carry
 
     return run
 
 
 def _scan_args(state: EngineState, factors, carry) -> tuple:
     """The all-modes program's arguments for ``state``."""
-    return ((state.val, state.idx, state.alpha), state.relabel, state.sched,
-            tuple(factors), carry)
+    return (_layout(state), state.relabel, state.sched, tuple(factors),
+            carry)
 
 
 def _arg_shape(x) -> jax.ShapeDtypeStruct:
@@ -349,9 +418,8 @@ def _scan_fn(state: EngineState, fold: FoldFn | None, args: tuple):
     key = ("all_modes", state.aux_key(), fold)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        donate = (0,) if state.config.resolve_donate() else ()
         fn = _JIT_CACHE[key] = jax.jit(_build_scan(state, fold),
-                                       donate_argnums=donate)
+                                       donate_argnums=_donate(state))
         _SCAN_ARGS[key] = jax.tree.map(_arg_shape, args)
     return fn
 
@@ -361,9 +429,9 @@ def all_modes(state: EngineState, factors: Sequence[jax.Array], *,
     """spMTTKRP along all N modes as ONE jitted ``lax.scan`` dispatch.
 
     Starts from the resident ``state.mode`` (any mode — the alpha tables
-    rotate the layout back to it by the end) and returns outputs indexed
-    by mode, i.e. ``outs[d]`` is the mode-``d`` MTTKRP of shape
-    ``(dims[d], R)``.
+    rotate the layout back to it by the end; a pinned layout never leaves
+    it) and returns outputs indexed by mode, i.e. ``outs[d]`` is the
+    mode-``d`` MTTKRP of shape ``(dims[d], R)``.
 
     Without ``fold``: returns ``(outs, next_state)``.
     With ``fold`` (stable module-level callable, see :data:`FoldFn`):
@@ -378,9 +446,8 @@ def all_modes(state: EngineState, factors: Sequence[jax.Array], *,
         _c.on_dispatch(state.config.backend)
     DISPATCH_COUNTS["all_modes"] += 1
     with span("engine.dispatch", kind="all_modes", start_mode=state.mode):
-        layout3, outs, out_factors, out_carry = fn(*args)
-    nval, nidx, nalpha = layout3
-    next_state = state.replace(val=nval, idx=nidx, alpha=nalpha)
+        layout, outs, out_factors, out_carry = fn(*args)
+    next_state = _advance(state, layout)
     if fold is None:
         return list(outs), next_state
     return list(outs), next_state, list(out_factors), out_carry
@@ -504,4 +571,5 @@ def _hlo_scopes(text: str) -> dict[str, OpScope]:
 
 __all__ = ["init", "mttkrp", "all_modes", "scan_jaxpr", "scan_hlo",
            "op_scopes", "OpScope", "reset_counters",
-           "TRACE_COUNTS", "DISPATCH_COUNTS", "ROW_COPIES", "FoldFn"]
+           "TRACE_COUNTS", "DISPATCH_COUNTS", "ROW_COPIES", "REMAP_SLOTS",
+           "FoldFn"]

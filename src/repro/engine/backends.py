@@ -29,6 +29,11 @@ which performs EC *and* the Alg. 3 remap scatter in one kernel pass; the
 engine's scan step delegates to it (unless ``config.fuse_remap`` is off)
 instead of issuing the XLA scatter of the slot records.
 
+A backend that reads nothing of the layout but ``val`` and ``lrow`` under
+the compact schedule declares ``pinned_layout = True``: ``engine.init``
+then pins each mode's ``(val, lrow)`` on the device (:func:`pins_layout`)
+and no step remaps.
+
 Registered backends:
   ============  =========================================================
   xla           fused segment-sum over the relabeled row space (default);
@@ -40,13 +45,14 @@ Registered backends:
                 descriptor-driven grid (``mttkrp_fused_compact``)
   pallas_fused  zero-HBM-intermediate Pallas pipeline: factor rows are
                 gathered *inside* the kernel grid (per-block row lists
-                DMA'd into SMEM + double-buffered ANY->VMEM row DMA) and the
-                Alg. 3 remap scatter is emitted by the same pass via
-                ``fused_remap``. Compact schedule: the gather is
-                *dedup-aware* — each block DMAs only its ``U <= P``
-                unique factor rows (plan-sorted ``uidx``/``nuniq``) and
-                the EC body routes slots through ``upos`` with a one-hot
-                MXU stage select
+                DMA'd into SMEM + double-buffered ANY->VMEM row DMA) and,
+                on a rotating layout, the Alg. 3 remap scatter is emitted
+                by the same pass via ``fused_remap``. Compact schedule: the
+                gather is *dedup-aware* — each block DMAs only its
+                ``U <= P`` unique factor rows (plan-sorted
+                ``uidx``/``nuniq``) and the EC body routes slots through
+                ``upos`` with a one-hot MXU stage select; it reads only
+                ``val`` and ``lrow`` of the layout (``pinned_layout``)
   ref           unfused oracle-shaped path: materialize the (S, R)
                 Hadamard partials, then segment-sum — the baseline the
                 paper's fusion argument (Fig. 7) is measured against
@@ -102,6 +108,14 @@ def get_backend(config_or_name: ExecutionConfig | str) -> ECBackend:
         raise KeyError(
             f"unknown engine backend {name!r}; registered: "
             f"{sorted(BACKENDS)}") from None
+
+
+def pins_layout(config: ExecutionConfig, statics) -> bool:
+    """Whether ``engine.init`` pins each mode's ``(val, lrow)``: the
+    configured backend declares ``pinned_layout`` and every mode runs the
+    compact schedule."""
+    return (getattr(get_backend(config), "pinned_layout", False)
+            and all(s.schedule == "compact" for s in statics))
 
 
 # --------------------------------------------------------------------------
@@ -275,8 +289,9 @@ def ec_pallas_fused(layout, factors, mode: int, *, plan: ModeStatic,
     ANY->VMEM row DMA), so no ``(N-1, R, S)`` intermediate is ever
     materialized. Under the compact schedule the gather is dedup-aware:
     each block DMAs only its unique factor rows. This entry is the
-    plain-EC contract used under ``shard_map`` too; the single-device scan
-    step upgrades to ``fused_remap`` below."""
+    plain-EC contract used under ``shard_map`` and on pinned states; the
+    single-device scan step of a rotating state upgrades to
+    ``fused_remap`` below."""
     from repro.kernels import ops as kops
 
     inputs = tuple(f for w, f in enumerate(factors) if w != mode)
@@ -365,8 +380,11 @@ ec_pallas_fused.fused_remap = _pallas_fused_remap
 # engine.init builds the per-mode dedup tables (EngineState.sched) only
 # for backends that declare they consume them.
 ec_pallas_fused.needs_dedup = True
+# Under the compact schedule the kernel reads only val and lrow of the
+# layout (the rect one reads idx, through _fused_lidx).
+ec_pallas_fused.pinned_layout = True
 
 
-__all__ = ["BACKENDS", "register_backend", "get_backend", "compute_lrow",
-           "pack_slots", "empty_slots", "unpack_slots", "scatter_slots",
-           "ec_xla", "ec_ref", "ec_pallas", "ec_pallas_fused"]
+__all__ = ["BACKENDS", "register_backend", "get_backend", "pins_layout",
+           "compute_lrow", "pack_slots", "empty_slots", "unpack_slots",
+           "scatter_slots", "ec_xla", "ec_ref", "ec_pallas", "ec_pallas_fused"]

@@ -85,9 +85,10 @@ class ExecutionConfig:
         donate only where XLA supports it (TPU/GPU).
       fuse_remap: let a fusing backend (one exposing ``fused_remap``, e.g.
         ``pallas_fused``) emit the Alg. 3 remap scatter inside its kernel
-        pass instead of the three full-``S_max`` XLA scatters in the scan
-        step. ``False`` forces the XLA scatter path for any backend (the
-        comparison baseline).
+        pass instead of the full-``S_max`` XLA scatter in the scan step.
+        ``False`` forces the XLA scatter path for any backend (the
+        comparison baseline). Moot on a pinned state, which never remaps
+        (``engine.state``).
       dedup: build the in-block factor-row dedup tables for backends that
         consume them (``needs_dedup``). ``False`` installs the trivial
         tables (one row DMA per slot) — same kernels, no host-side

@@ -351,8 +351,14 @@ def shard_state(state: EngineState, mesh: Mesh | ShardingCtx,
     collective_permute :class:`ExchangeSchedule` from the alpha tables, and
     ``device_put``s the arrays with the matching ``NamedSharding``s.
     Requires every mode's ``kappa`` to be a multiple of the data-axis size
-    (see :meth:`ExecutionConfig.kappa_for`).
+    (see :meth:`ExecutionConfig.kappa_for`), and the rotating layout
+    (``engine.init(..., _rotating=True)``): a pinned state holds no alpha
+    tables to renumber.
     """
+    if state.pinned is not None:
+        raise ValueError(
+            "shard_state re-lays the rotating (val, idx, alpha) layout; "
+            "build the state with engine.init(..., _rotating=True)")
     if isinstance(mesh, ShardingCtx):
         ctx, mesh = mesh, mesh.mesh
         if dist is None:

@@ -265,7 +265,8 @@ def make_engine(tensor, spec: PlanSpec | None = None, *,
             try:
                 if cz is not None:
                     cz.on_resident_init()
-                state = init(tensor, config, start_mode, cache=cache)
+                state = init(tensor, config, start_mode, cache=cache,
+                             _rotating=mesh is not None)
             except Exception as exc:
                 # residency rung of the degradation ladder: the full
                 # layout doesn't fit -> stream it (single-device only;

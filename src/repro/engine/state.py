@@ -1,29 +1,42 @@
 """Pytree engine state for the functional spMTTKRP engine.
 
 ``EngineState`` is the device-resident half of a
-:class:`~repro.core.flycoo.FlycooTensor`: the *current* FLYCOO layout
-(val/idx/alpha), padded to the uniform slot count ``S_max = max_d S_d`` so
-the same pytree shape serves every mode — which is exactly what makes the
-mode loop a ``lax.scan`` carry and the T_in/T_out swap a buffer donation
-instead of a host round-trip.
+:class:`~repro.core.flycoo.FlycooTensor`, in one of two layouts:
+
+* **rotating** — the *current* FLYCOO layout (val/idx/alpha), padded to the
+  uniform slot count ``S_max = max_d S_d`` so the same pytree shape serves
+  every mode — which is exactly what makes the mode loop a ``lax.scan``
+  carry and the T_in/T_out swap a buffer donation instead of a host
+  round-trip. Each step remaps it to the next mode (Alg. 3).
+* **pinned** — for a backend that reads only ``val`` and ``lrow`` of the
+  layout (``pinned_layout``, under the compact schedule): each mode's
+  ``(val, lrow)`` held on the device from init on, so no step remaps.
+  ``val``/``idx``/``alpha`` are ``None``. It holds ``8 * sum_d S_d`` bytes
+  where the rotating triple holds ``(4 + 8N) * S_max``.
 
 Array leaves (pytree children):
-  val      (S_max,)     f32   nonzero values, 0 in pads
+  val      (S_max,)     f32   nonzero values, 0 in pads (rotating)
   idx      (S_max, N)   i32   beta — original per-mode indices, 0 in pads
+                              (rotating)
   alpha    (S_max, N)   i32   alpha — slot of the element in every mode
-                              layout (-1 in pads)
+                              layout (-1 in pads) (rotating)
   relabel  N x (I_d,)   i32   old row id -> relabeled row id, per mode
   sched    N x ModeSched      per-mode block-schedule tables: the block ->
                               partition descriptor and (compact schedule)
                               the in-block factor-row dedup tables. Unlike
                               the layout triple these never remap — they
                               describe the mode-d slot space itself.
+  pinned   N x PinnedMode     each mode's (val, lrow), never remapped
+                              (pinned); None on a rotating state
 
 Static aux_data (hashable, part of the jit cache key):
-  mode     int                 which mode's layout is resident
+  mode     int                 the mode the next step computes (whose
+                               layout is resident, when rotating)
   dims     tuple[int, ...]
   statics  tuple[ModeStatic]   per-mode plan constants (kappa, rows_pp, ...)
   config   ExecutionConfig
+  layout   str                 "pinned" | "rotating" (derived from
+                               ``pinned``; part of ``aux_key``)
 """
 from __future__ import annotations
 
@@ -76,6 +89,18 @@ class ModeSched(NamedTuple):
     nuniq: Optional[jax.Array] = None
 
 
+class PinnedMode(NamedTuple):
+    """One mode's layout as the compact fused kernel reads it, held on the
+    device from init on (pytree of array leaves):
+
+      val   (S_d,)  f32  nonzero values, 0 in pads
+      lrow  (S_d,)  i32  relabeled row local to its partition, -1 in pads
+    """
+
+    val: jax.Array
+    lrow: jax.Array
+
+
 def mode_static_from_plan(plan) -> ModeStatic:
     return ModeStatic(kappa=plan.kappa, rows_pp=plan.rows_pp,
                       blocks_pp=plan.blocks_pp, block_p=plan.block_p,
@@ -88,15 +113,16 @@ def mode_static_from_plan(plan) -> ModeStatic:
 class EngineState:
     """Immutable, pytree-registered engine state (see module docstring)."""
 
-    val: jax.Array
-    idx: jax.Array
-    alpha: jax.Array
+    val: Optional[jax.Array]
+    idx: Optional[jax.Array]
+    alpha: Optional[jax.Array]
     relabel: tuple[jax.Array, ...]
     sched: tuple[ModeSched, ...]
     mode: int
     dims: tuple[int, ...]
     statics: tuple[ModeStatic, ...]
     config: ExecutionConfig
+    pinned: Optional[tuple[PinnedMode, ...]] = None
 
     # ------------------------------------------------------------ derived
     @property
@@ -117,9 +143,13 @@ class EngineState:
     def imax(self) -> int:
         return max(self.dims)
 
+    @property
+    def layout(self) -> str:
+        return "rotating" if self.pinned is None else "pinned"
+
     def aux_key(self):
         """Hashable key identifying every static property of this state."""
-        return (self.mode, self.dims, self.statics, self.config)
+        return (self.mode, self.dims, self.statics, self.config, self.layout)
 
     def replace(self, **kw) -> "EngineState":
         return dataclasses.replace(self, **kw)
@@ -127,17 +157,19 @@ class EngineState:
     # ------------------------------------------------------------- pytree
     def tree_flatten(self):
         children = (self.val, self.idx, self.alpha, self.relabel,
-                    self.sched)
+                    self.sched, self.pinned)
         aux = (self.mode, self.dims, self.statics, self.config)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        val, idx, alpha, relabel, sched = children
+        val, idx, alpha, relabel, sched, pinned = children
         mode, dims, statics, config = aux
         return cls(val=val, idx=idx, alpha=alpha, relabel=tuple(relabel),
                    sched=tuple(sched), mode=mode, dims=dims,
-                   statics=statics, config=config)
+                   statics=statics, config=config,
+                   pinned=None if pinned is None else tuple(pinned))
 
 
-__all__ = ["EngineState", "ModeStatic", "ModeSched", "mode_static_from_plan"]
+__all__ = ["EngineState", "ModeStatic", "ModeSched", "PinnedMode",
+           "mode_static_from_plan"]

@@ -74,7 +74,7 @@ from repro.resilience.ladder import (backoff_delay, classify, next_backend,
 from repro.resilience.snapshot import as_store, fingerprint
 
 from .api import _JIT_CACHE, DISPATCH_COUNTS, TRACE_COUNTS, _as_flycoo
-from .backends import get_backend
+from .backends import get_backend, pins_layout
 from .config import ExecutionConfig
 from .dist import row_bytes
 from .state import ModeStatic, mode_static_from_plan
@@ -161,15 +161,19 @@ def resolve_chunk_slots(config: ExecutionConfig, dims: Sequence[int], *,
 def resident_bytes(tensor, config: ExecutionConfig,
                    rank: int | None = None) -> int:
     """Device footprint of the FULL-residency engine (``engine.init``) for
-    ``tensor``: the S_max-padded layout triple, the per-mode schedule
-    tables, the relabel tables, the factors and one rotation of outputs.
+    ``tensor``: the S_max-padded layout triple (or, pinned, every mode's
+    ``(val, lrow)``), the per-mode schedule tables, the relabel tables, the
+    factors and one rotation of outputs.
     This is the threshold ``residency="auto"`` compares
     ``device_budget_bytes`` against."""
     rank = rank or config.rank_hint
     n = tensor.nmodes
     statics = [mode_static_from_plan(p) for p in tensor.plans]
     smax = max(s.padded_nnz for s in statics)
-    total = smax * 4 * (1 + 2 * n)            # val + idx + alpha
+    if pins_layout(config, statics):
+        total = sum(s.padded_nnz for s in statics) * 8   # val + lrow
+    else:
+        total = smax * 4 * (1 + 2 * n)            # val + idx + alpha
     tables = _wants_tables(config, statics[0].schedule)
     for s in statics:
         total += s.nblocks * 4                 # bpart descriptor

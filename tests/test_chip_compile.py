@@ -108,13 +108,14 @@ def test_fused_remap_vmem_bound_raises():
     t = build_flycoo(idx, val, dims, rows_pp=64)
     factors = init_factors(jax.random.PRNGKey(0), dims, 8)
     cfg = ExecutionConfig(backend="pallas_fused", interpret=False)
-    state = engine.init(t, cfg)
+    # the fused remap runs on the rotating layout (a pinned one has none)
+    state = engine.init(t, cfg, _rotating=True)
     assert state.smax > 87_000
     with pytest.raises(ValueError, match="VMEM"):
         engine.scan_jaxpr(state, factors)
     # interpret mode keeps nothing in VMEM: the same plan traces
     engine.scan_jaxpr(engine.init(t, ExecutionConfig(
-        backend="pallas_fused", interpret=True)), factors)
+        backend="pallas_fused", interpret=True), _rotating=True), factors)
     K.check_fused_remap_fits(8_192, 3, 8, 64, P)
     with pytest.raises(ValueError, match="fuse_remap=False"):
         K.check_fused_remap_fits(90_000, 5, 32, 512, P)

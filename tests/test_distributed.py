@@ -154,7 +154,7 @@ def test_engine_dist_pallas_fused_backend_parity():
         refs = [mttkrp_ref(jnp.asarray(idx), jnp.asarray(val), factors, d,
                            dims[d]) for d in range(3)]
         cfg = engine.ExecutionConfig(backend="pallas_fused", interpret=True)
-        state = engine.init(t, cfg)
+        state = engine.init(t, cfg, _rotating=True)
         mesh = make_mesh((4,), ("data",))
         ds = engine.dist.shard_state(state, mesh)
         for sweep in range(2):
@@ -296,7 +296,8 @@ def test_dist_mode_shorter_than_mesh():
         mesh = make_mesh((4,), ("data",))
         for backend in ("xla", "pallas_fused"):
             st = engine.dist.shard_state(engine.init(
-                t, ExecutionConfig(backend=backend, interpret=True)), mesh)
+                t, ExecutionConfig(backend=backend, interpret=True),
+                _rotating=True), mesh)
             outs, st = engine.dist.dist_all_modes(st, factors)
             for d in range(len(dims)):
                 ref = mttkrp_ref(jnp.asarray(idx), jnp.asarray(val),

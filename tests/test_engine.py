@@ -90,29 +90,44 @@ def test_xla_backend_chunked_reduction(monkeypatch, chunk):
 
 @pytest.mark.parametrize("nmodes", [3, 4, 5, 6])
 def test_pallas_fused_any_start_and_step(nmodes):
-    """The fused EC+remap pipeline works from any resident mode, both as
-    the scanned rotation and stepped one dispatch at a time."""
+    """The fused EC+remap pipeline (on the rotating layout) works from any
+    resident mode, both as the scanned rotation and stepped one dispatch
+    at a time."""
     dims = DIMS_BY_NMODES[nmodes]
     idx, val, t = _tensor(nmodes + 20, dims, 600, rows_pp=4, block_p=8)
     factors = tuple(init_factors(jax.random.PRNGKey(5), dims, 8))
     refs = _refs(idx, val, factors, dims)
     cfg = ExecutionConfig(backend="pallas_fused", interpret=True)
     for start in (0, nmodes - 1):
-        state = engine.init(t, cfg, start_mode=start)
+        state = engine.init(t, cfg, start_mode=start, _rotating=True)
         outs, state = engine.all_modes(state, factors)
         assert state.mode == start
         for d in range(nmodes):
             np.testing.assert_allclose(outs[d], refs[d], rtol=2e-4,
                                        atol=2e-4)
-    state = engine.init(t, cfg, start_mode=1)
+    state = engine.init(t, cfg, start_mode=1, _rotating=True)
     for i in range(nmodes):
         out, state = engine.mttkrp(state, factors)
         np.testing.assert_allclose(out, refs[(1 + i) % nmodes], rtol=2e-4,
                                    atol=2e-4)
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas", "pallas_fused", "ref"])
-def test_pad_slots_cannot_pollute_row_zero(backend):
+def _poison_pads(state, value):
+    """``state`` with ``value`` in every pad slot's val: the pinned pads of
+    every mode, or the pads of the resident rotating layout."""
+    if state.pinned is not None:
+        return state.replace(pinned=tuple(
+            p._replace(val=jnp.where(p.lrow < 0, value, p.val))
+            for p in state.pinned))
+    return state.replace(
+        val=jnp.where(state.alpha[:, state.mode] < 0, value, state.val))
+
+
+@pytest.mark.parametrize("backend,rotating", [
+    pytest.param(b, False, id=b) for b in
+    ("xla", "pallas", "pallas_fused", "ref")] + [
+    pytest.param("pallas_fused", True, id="pallas_fused-rotating")])
+def test_pad_slots_cannot_pollute_row_zero(backend, rotating):
     """Pad slots (lrow == -1) are dumped into segment 0 by the XLA
     segment-sum paths and carry in-bounds idx = 0 — so their contribution
     must be masked structurally, not by relying on pad val == 0. Forcing
@@ -122,9 +137,11 @@ def test_pad_slots_cannot_pollute_row_zero(backend):
     idx, val, t = _tensor(8, dims, 500, rows_pp=4, block_p=8)
     factors = tuple(init_factors(jax.random.PRNGKey(7), dims, 8))
     refs = _refs(idx, val, factors, dims)
-    state = engine.init(t, ExecutionConfig(backend=backend, interpret=True))
-    poisoned = state.replace(
-        val=jnp.where(state.alpha[:, state.mode] < 0, 7.25, state.val))
+    state = engine.init(t, ExecutionConfig(backend=backend, interpret=True),
+                        _rotating=rotating)
+    assert state.layout == ("pinned" if backend == "pallas_fused"
+                            and not rotating else "rotating")
+    poisoned = _poison_pads(state, 7.25)
     outs, _ = engine.all_modes(poisoned, factors)
     for d in range(4):
         np.testing.assert_allclose(outs[d], refs[d], rtol=2e-4, atol=2e-4)
@@ -188,14 +205,13 @@ def test_all_modes_is_single_scanned_dispatch():
 # (S_d, N-1, R) gathered buffer (the unfused pallas backend does).
 # --------------------------------------------------------------------------
 def _scan_hlo(t, backend, factors):
-    from repro.engine.api import _build_scan
+    from repro.engine.api import _build_scan, _scan_args
 
     state = engine.init(t, ExecutionConfig(backend=backend, interpret=True,
                                            donate=False))
     fn = _build_scan(state, None)
     return state, jax.jit(fn).lower(
-        (state.val, state.idx, state.alpha), state.relabel, state.sched,
-        tuple(factors), None).as_text()
+        *_scan_args(state, factors, None)).as_text()
 
 
 def test_fused_scan_has_no_gathered_intermediate():
@@ -220,17 +236,20 @@ def test_fused_scan_has_no_gathered_intermediate():
 
 
 def test_fuse_remap_knob_and_vmem_budget():
-    """fuse_remap=False forces the XLA scatter path (bit-parity with the
-    fused one); vmem_budget_bytes sizes the vmem-policy row tiles."""
+    """fuse_remap=False forces the XLA scatter path on a rotating layout
+    (bit-parity with the fused one); vmem_budget_bytes sizes the
+    vmem-policy row tiles."""
     dims = DIMS_BY_NMODES[3]
     idx, val, t = _tensor(12, dims, 400, rows_pp=4, block_p=8)
     factors = tuple(init_factors(jax.random.PRNGKey(3), dims, 8))
     outs_f, _ = engine.all_modes(
         engine.init(t, ExecutionConfig(backend="pallas_fused",
-                                       interpret=True)), factors)
+                                       interpret=True), _rotating=True),
+        factors)
     outs_u, _ = engine.all_modes(
         engine.init(t, ExecutionConfig(backend="pallas_fused",
-                                       interpret=True, fuse_remap=False)),
+                                       interpret=True, fuse_remap=False),
+                    _rotating=True),
         factors)
     for a, b in zip(outs_f, outs_u):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
@@ -481,11 +500,12 @@ def _without_metadata(text: str) -> str:
     return _METADATA.sub("", _SOURCE_TABLES.sub("", text))
 
 
-def _sweep(backend, seed=21, **cfg):
+def _sweep(backend, seed=21, rotating=False, **cfg):
     dims = DIMS_BY_NMODES[4]
     _, _, t = _tensor(seed, dims, 700, rows_pp=4, block_p=8)
     state = engine.init(t, ExecutionConfig(backend=backend, interpret=True,
-                                           donate=False, **cfg))
+                                           donate=False, **cfg),
+                        _rotating=rotating)
     factors = tuple(init_factors(jax.random.PRNGKey(4), dims, 8))
     return t, state, factors, jnp.ones((8,), jnp.float32)
 
@@ -499,12 +519,19 @@ def test_sweep_scopes_in_compiled_hlo():
             assert any(f"/mode{d}/{scope}/" in n for n in names), (d, scope)
 
 
-@pytest.mark.parametrize("backend,fuse_remap", [
-    ("xla", True), ("pallas_fused", False), ("pallas_fused", True)])
-def test_sweep_scopes_change_only_metadata(monkeypatch, backend, fuse_remap):
+@pytest.mark.parametrize("backend,fuse_remap,rotating", [
+    pytest.param("xla", True, False, id="xla-True"),
+    pytest.param("pallas_fused", False, False, id="pallas_fused-False"),
+    pytest.param("pallas_fused", True, False, id="pallas_fused-True"),
+    pytest.param("pallas_fused", True, True, id="pallas_fused-True-rotating"),
+])
+def test_sweep_scopes_change_only_metadata(monkeypatch, backend, fuse_remap,
+                                           rotating):
     """Without the named scopes the optimized program is the same, apart
-    from metadata: instruction for instruction, names included."""
-    _, state, factors, lam = _sweep(backend, fuse_remap=fuse_remap)
+    from metadata: instruction for instruction, names included (pinned
+    and rotating layouts)."""
+    _, state, factors, lam = _sweep(backend, fuse_remap=fuse_remap,
+                                    rotating=rotating)
     args = engine.api._scan_args(state, factors, lam)
 
     def compiled():
@@ -569,3 +596,4 @@ def test_engine_row_copies_equal_the_nuniq_sums(dedup):
     engine.init(t, ExecutionConfig(backend="xla"))
     assert [engine.api.ROW_COPIES[d] for d in range(t.nmodes)] == \
         [0] * t.nmodes
+

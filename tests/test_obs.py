@@ -290,10 +290,10 @@ def test_engine_upload_span_after_init_with_its_bytes(tracer):
     spans = {s.name: s for s in tracer.spans()}
     init, up = spans["engine.init"], spans["engine.upload"]
     assert up.parent_id is None and up.start_ns >= init.end_ns
-    # every array device_place hands over: the layout, the relabel tables
-    # and each mode's schedule and dedup tables
-    n, smax = t.nmodes, state.smax
-    expect = smax * 4 * (1 + 2 * n)
+    # every array device_place hands over: each mode's pinned (val, lrow),
+    # the relabel tables and each mode's schedule and dedup tables
+    assert state.layout == init.attrs["layout"] == "pinned"
+    expect = sum(8 * p.padded_nnz for p in t.plans)
     expect += sum(4 * p.row_relabel.size for p in t.plans)
     for d, p in enumerate(t.plans):
         uidx, upos, nuniq = t.dedup_tables(d)
